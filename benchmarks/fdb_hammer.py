@@ -1061,6 +1061,9 @@ def _remote_proc_worker(addr: str, spec_kw: dict, member: int, mode: str):
     """One hammer client as a REAL OS process: its own RemoteFDB (own
     sockets, own GIL), one wire frame per output-step batch.  Module
     top-level so ``multiprocessing`` spawn can pickle it by reference.
+    It moves raw bytes only and never runs the codec: a device belongs to
+    one process, so N children must not reach for it (``main`` refuses
+    ``--remote`` with ``--codec-nbits``).
     Returns wall-clock ``(start, end)`` — ``time.time()`` because the global
     timing span (paper §4.3) is computed ACROSS processes, and only the
     wall clock is shared between them."""
@@ -1259,6 +1262,14 @@ def main() -> None:
     ap.add_argument("--cache-bytes", type=int, default=256 << 20, metavar="B",
                     help="cache tier byte budget for --cache (default 256 MiB)")
     args = ap.parse_args()
+    if args.remote and args.codec_nbits is not None:
+        ap.error("--remote runs raw-byte client processes and cannot take "
+                 "--codec-nbits: the codec runs on the device, and one device "
+                 "belongs to one process, not to every client")
+
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     spec = HammerSpec(n_procs=args.procs, n_steps=args.steps, n_params=args.params,
                       n_levels=args.levels, field_size=args.field_size, io=args.io,

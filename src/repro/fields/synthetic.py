@@ -2,10 +2,12 @@
 
 Cheap spectral synthesis: a few random low-order zonal/meridional harmonics
 plus noise — smooth, bounded 2-D fields resembling global analysis slices,
-deterministic per (param, member, step).
+deterministic per (param, level, member, step, seed) in every process.
 """
 
 from __future__ import annotations
+
+import zlib
 
 import numpy as np
 
@@ -28,14 +30,19 @@ def synthetic_field(
     member: int = 0,
     step: int = 0,
     *,
+    level: int = 0,
+    seed: int = 0,
     nlat: int = 181,
     nlon: int = 360,
     n_modes: int = 6,
 ) -> np.ndarray:
-    """(nlat, nlon) float32 field, deterministic in (param, member, step)."""
+    """(nlat, nlon) float32 field, deterministic in (param, level, member,
+    step, seed).  The key is digested with crc32, not ``hash()``: string
+    hashing is salted per process, and the fields must be the same in
+    every run."""
     base, scale = FIELD_BASE.get(param, (0.0, 1.0))
-    seed = abs(hash((param, member, step))) % (2**31)
-    rng = np.random.default_rng(seed)
+    digest = zlib.crc32(repr((param, level, member, step)).encode())
+    rng = np.random.default_rng([digest, seed])
     lat = np.linspace(-np.pi / 2, np.pi / 2, nlat)[:, None]
     lon = np.linspace(0, 2 * np.pi, nlon, endpoint=False)[None, :]
     f = np.zeros((nlat, nlon))

@@ -43,9 +43,7 @@ def grib_pack(x: jax.Array, *, nbits: int = 16, interpret: bool | None = None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     ref, scale, inv_scale = field_stats(x, nbits)
-    codes = grib_pack_call(
-        x, ref[:, None], inv_scale[:, None], nbits=nbits, interpret=interpret
-    )
+    codes = grib_pack_call(x, ref, inv_scale, nbits=nbits, interpret=interpret)
     return codes, ref, scale
 
 
@@ -53,7 +51,7 @@ def grib_pack(x: jax.Array, *, nbits: int = 16, interpret: bool | None = None):
 def grib_unpack(codes: jax.Array, ref: jax.Array, scale: jax.Array, *, interpret: bool | None = None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return grib_unpack_call(codes, ref[:, None], scale[:, None], interpret=interpret)
+    return grib_unpack_call(codes, ref, scale, interpret=interpret)
 
 
 def pack_to_bytes(x: np.ndarray, nbits: int = 16) -> tuple[bytes, dict]:

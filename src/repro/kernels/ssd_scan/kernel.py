@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 __all__ = ["ssd_scan_kernel", "ssd_scan_call"]
 
 
@@ -114,7 +112,7 @@ def ssd_scan_call(
         out_specs=pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

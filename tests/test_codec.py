@@ -3,6 +3,7 @@ client surface (archive_fields/retrieve_fields), per-tier config widths,
 effective-vs-wire telemetry, and the hammer's codec cells."""
 
 import json
+import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -539,3 +540,14 @@ class TestHammerCodec:
         assert row["effective_bytes_written"] == spec.total_bytes
         assert row["wire_bytes_written"] > 0
         assert row["codec_ratio_w"] > 1.0  # hot 16-bit tier wins, cold 24 rides uint32
+
+    def test_remote_refuses_codec(self, hammer, monkeypatch, capsys):
+        """Remote clients are separate processes that move raw bytes; the
+        codec needs the device, which one process holds."""
+        monkeypatch.setattr(
+            sys, "argv", ["fdb_hammer.py", "--remote", "--codec-nbits", "16"]
+        )
+        with pytest.raises(SystemExit) as exc:
+            hammer.main()
+        assert exc.value.code == 2
+        assert "--codec-nbits" in capsys.readouterr().err
