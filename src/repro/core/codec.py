@@ -47,7 +47,6 @@ from __future__ import annotations
 import struct
 import threading
 import time
-from collections import Counter
 from typing import Iterator, Mapping, Sequence
 
 import numpy as np
@@ -70,9 +69,7 @@ __all__ = [
     "decode_payloads",
     "encode_fields",
     "is_codec_payload",
-    "kernel_launches",
     "parse_header",
-    "reset_kernel_launches",
     "take_fields",
     "wire_size",
 ]
@@ -86,28 +83,6 @@ _MAGIC = b"GRPK"
 _VERSION = 1
 _HEADER_FMT = "<4sBBHIIdd"  # magic, version, nbits, reserved, H, W, ref, scale
 CODEC_HEADER_SIZE = struct.calcsize(_HEADER_FMT)  # 32 bytes
-
-#: pack/unpack kernel-launch counters — the batch-fusion contract ("one
-#: launch per batch") is asserted in tests against these, not inferred
-_LAUNCHES: Counter = Counter()
-_LAUNCH_MU = threading.Lock()
-
-
-def kernel_launches() -> dict:
-    """Snapshot of cumulative {'pack': n, 'unpack': m} kernel launches."""
-    with _LAUNCH_MU:
-        return {"pack": _LAUNCHES["pack"], "unpack": _LAUNCHES["unpack"]}
-
-
-def reset_kernel_launches() -> None:
-    with _LAUNCH_MU:
-        _LAUNCHES.clear()
-
-
-def _count_launch(kind: str) -> None:
-    with _LAUNCH_MU:
-        _LAUNCHES[kind] += 1
-
 
 class CodecHeader:
     """Parsed wire header of one codec payload."""
@@ -217,7 +192,11 @@ def encode_fields(fields, *, nbits: int = 16, stats=None, tracer=None) -> list[b
     distinct shape when ragged) — the per-launch dispatch cost is amortised
     exactly like the backends amortise per-op I/O costs in
     ``archive_batch``.  Returns one payload per field, in input order.
-    ``tracer`` records one span per kernel launch with effective/wire bytes.
+    ``tracer`` records one ``codec.pack`` span per kernel launch with
+    effective/wire bytes, and inside it ``codec.pack.stack`` (the batch
+    stacked), ``codec.pack.device`` (the kernel call until codes, ref and
+    scale are host arrays) and ``codec.pack.frame`` (the narrowing cast and
+    the framing).
     """
     dtype = payload_dtype(nbits)  # validates nbits before any device work
     tr = tracer if tracer is not None else NULL_TRACER
@@ -232,17 +211,20 @@ def encode_fields(fields, *, nbits: int = 16, stats=None, tracer=None) -> list[b
     for shape, idxs in groups.items():
         h, w = shape
         with tr.span("codec.pack") as sp:
-            batch = np.stack([flist[i] for i in idxs])  # (f, H, W) float32
-            _count_launch("pack")
-            codes, ref, scale = grib_pack(batch, nbits=nbits)
-            codes = np.asarray(codes).astype(dtype)
-            ref = np.asarray(ref, dtype=np.float64)
-            scale = np.asarray(scale, dtype=np.float64)
-            for j, i in enumerate(idxs):
-                header = struct.pack(
-                    _HEADER_FMT, _MAGIC, _VERSION, nbits, 0, h, w, ref[j], scale[j]
-                )
-                payloads[i] = header + codes[j].tobytes()
+            with tr.span("codec.pack.stack"):
+                batch = np.stack([flist[i] for i in idxs])  # (f, H, W) float32
+            with tr.span("codec.pack.device"):
+                codes, ref, scale = grib_pack(batch, nbits=nbits)
+                codes = np.asarray(codes)  # int32, device to host
+                ref = np.asarray(ref, dtype=np.float64)
+                scale = np.asarray(scale, dtype=np.float64)
+            with tr.span("codec.pack.frame"):
+                codes = codes.astype(dtype)
+                for j, i in enumerate(idxs):
+                    header = struct.pack(
+                        _HEADER_FMT, _MAGIC, _VERSION, nbits, 0, h, w, ref[j], scale[j]
+                    )
+                    payloads[i] = header + codes[j].tobytes()
             if tr.enabled:
                 sp.set("nbits", nbits)
                 sp.set("fields", len(idxs))
@@ -275,7 +257,10 @@ def decode_payloads(
     ``None`` entries (absent fields) pass through.  All payloads decode in
     ONE ``grib_unpack`` kernel launch per distinct field shape.  ``labels``
     (e.g. the MARS keys) contextualise :class:`CodecError` messages.
-    ``tracer`` records one span per kernel launch with effective/wire bytes.
+    ``tracer`` records one ``codec.unpack`` span per kernel launch with
+    effective/wire bytes, and inside it ``codec.unpack.stack`` (codes
+    widened to int32 and stacked, with ref and scale) and
+    ``codec.unpack.device`` (the kernel call until the decoded host array).
     """
     tr = tracer if tracer is not None else NULL_TRACER
     t0 = time.perf_counter()
@@ -291,19 +276,20 @@ def decode_payloads(
         groups.setdefault((hdr.height, hdr.width, hdr.nbits), []).append(i)
     for (h, w, nbits), idxs in groups.items():
         with tr.span("codec.unpack") as sp:
-            dtype = payload_dtype(nbits)
-            codes = np.stack(
-                [
-                    np.frombuffer(payloads[i], dtype=dtype, offset=CODEC_HEADER_SIZE)
-                    .reshape(h, w)
-                    .astype(np.int32)
-                    for i in idxs
-                ]
-            )
-            ref = np.asarray([headers[i].ref for i in idxs], dtype=np.float32)
-            scale = np.asarray([headers[i].scale for i in idxs], dtype=np.float32)
-            _count_launch("unpack")
-            decoded = np.asarray(grib_unpack(codes, ref, scale))
+            with tr.span("codec.unpack.stack"):
+                dtype = payload_dtype(nbits)
+                codes = np.stack(
+                    [
+                        np.frombuffer(payloads[i], dtype=dtype, offset=CODEC_HEADER_SIZE)
+                        .reshape(h, w)
+                        .astype(np.int32)
+                        for i in idxs
+                    ]
+                )
+                ref = np.asarray([headers[i].ref for i in idxs], dtype=np.float32)
+                scale = np.asarray([headers[i].scale for i in idxs], dtype=np.float32)
+            with tr.span("codec.unpack.device"):
+                decoded = np.asarray(grib_unpack(codes, ref, scale))
             for j, i in enumerate(idxs):
                 out[i] = decoded[j]
             if tr.enabled:

@@ -40,6 +40,7 @@ import time
 from typing import Iterator, Mapping, Sequence
 
 from ...metrics.iostats import IOStats
+from ...obs.tracer import NULL_TRACER
 from ..catalogue import ListEntry
 from ..client import FDBClient, WipeReport
 from ..datahandle import DataHandle, MemoryDataHandle
@@ -93,12 +94,22 @@ class _Conn:
             self.sock.close()
             raise
 
-    def call(self, req_id: int, opcode: int, payload: bytes) -> tuple[int, Cursor, int]:
+    def call(
+        self, req_id: int, opcode: int, payload: bytes, tracer=NULL_TRACER
+    ) -> tuple[int, Cursor, int]:
         """Send one frame, block for its response.  Returns
-        ``(response opcode, payload cursor, response bytes)``."""
-        self.sock.sendall(P.encode_frame(req_id, opcode, payload))
-        body = self._recv_frame()
-        resp_id, resp_op, cur = P.split_frame(body)
+        ``(response opcode, payload cursor, response bytes)``.  ``tracer``
+        records ``wire.send`` (framing and ``sendall``) and ``wire.recv``
+        (from the reply's 4-byte header to the split body); the time between
+        them is the wait for the server."""
+        with tracer.span("wire.send"):
+            self.sock.sendall(P.encode_frame(req_id, opcode, payload))
+        hdr = self._recv_exact(4, "frame header")
+        with tracer.span("wire.recv"):
+            body = self._recv_exact(
+                P.frame_length(hdr, max_frame=self._max_frame), "frame"
+            )
+            resp_id, resp_op, cur = P.split_frame(body)
         if resp_id != req_id:
             raise ProtocolError(
                 f"response id {resp_id} does not match request id {req_id}"
@@ -114,12 +125,6 @@ class _Conn:
             chunks.append(chunk)
             n -= len(chunk)
         return b"".join(chunks)
-
-    def _recv_frame(self) -> bytes:
-        hdr = self._recv_exact(4, "frame header")
-        return self._recv_exact(
-            P.frame_length(hdr, max_frame=self._max_frame), "frame"
-        )
 
     def close(self) -> None:
         try:
@@ -220,7 +225,8 @@ class RemoteFDB(FDBClient):
         """One request/response round with pooling, timeout mapping and
         bounded retry on transport faults.
 
-        The whole round runs under a wire span.  When tracing is on AND the
+        The whole round runs under a wire span, with ``wire.send`` and
+        ``wire.recv`` children per attempt.  When tracing is on AND the
         connection negotiated the trace extension, the frame goes out
         TRACE_FLAG'd with this span's context prefixed, so the server's op
         span becomes a child of the wire span — the send/receive time and
@@ -250,7 +256,7 @@ class RemoteFDB(FDBClient):
                 req_id = self._next_req_id()
                 t0 = time.perf_counter()
                 try:
-                    resp_op, cur, nread = conn.call(req_id, wire_op, wire_payload)
+                    resp_op, cur, nread = conn.call(req_id, wire_op, wire_payload, tr)
                 except _TRANSPORT_FAULTS as e:
                     conn.close()
                     self._pool.put(None)

@@ -194,7 +194,9 @@ class FDBServer:
             await self._handshake(reader, writer, wlock, conn)
             # bounded frame queue: the reader below stops pulling off the
             # socket once max_inflight frames are pending, so TCP flow
-            # control is the backpressure all the way to the client
+            # control is the backpressure all the way to the client.  Each
+            # frame rides with the moment its body was read, so a traced op
+            # can tell how long it waited for a server thread
             q: asyncio.Queue = asyncio.Queue(maxsize=self._max_inflight)
             worker = asyncio.create_task(self._conn_worker(q, writer, wlock, conn))
             try:
@@ -202,7 +204,7 @@ class FDBServer:
                     body = await self._read_frame(reader)
                     if body is None:
                         break
-                    await q.put(body)
+                    await q.put((body, time.perf_counter()))
             finally:
                 await q.put(_EOF)
                 await worker
@@ -290,35 +292,38 @@ class FDBServer:
             pending = None
             if item is _EOF:
                 return
-            req_id, opcode, _ = P.split_frame(item)
+            body, t_read = item
+            req_id, opcode, _ = P.split_frame(body)
             if P.mask_op(opcode)[0] == Op.ARCHIVE_BATCH:
                 # wire-level batching: drain whatever archive frames are
                 # already queued into one backend round (the TRACE_FLAG bit
                 # is per-frame — masked off before comparing opcodes)
-                frames = [item]
+                frames = [body]
                 while len(frames) < self._coalesce:
                     try:
                         nxt = q.get_nowait()
                     except asyncio.QueueEmpty:
                         break
-                    if nxt is _EOF or P.mask_op(P.split_frame(nxt)[1])[0] != Op.ARCHIVE_BATCH:
+                    if nxt is _EOF or P.mask_op(P.split_frame(nxt[0])[1])[0] != Op.ARCHIVE_BATCH:
                         pending = nxt
                         break
-                    frames.append(nxt)
-                await self._run_archive_group(frames, writer, wlock, conn)
+                    frames.append(nxt[0])
+                await self._run_archive_group(frames, t_read, writer, wlock, conn)
                 continue
             try:
-                await self._run_op(item, writer, wlock, conn)
+                await self._run_op(body, t_read, writer, wlock, conn)
             except (ConnectionError, OSError):
                 return  # peer gone: nothing left to answer
 
-    async def _run_archive_group(self, frames: list[bytes], writer, wlock, conn: str) -> None:
+    async def _run_archive_group(
+        self, frames: list[bytes], t_read: float, writer, wlock, conn: str
+    ) -> None:
         loop = asyncio.get_running_loop()
         t0 = time.perf_counter()
         try:
             nbytes_in = sum(len(f) for f in frames)
             merged = await loop.run_in_executor(
-                self._executor, self._archive_frames, frames
+                self._executor, self._archive_frames, frames, t_read
             )
             err = None
         except asyncio.CancelledError:
@@ -339,12 +344,14 @@ class FDBServer:
             else:
                 await self._send(writer, wlock, req_id, Op.ERR, P.encode_error(err))
 
-    def _archive_frames(self, frames: list[bytes]) -> int:
+    def _archive_frames(self, frames: list[bytes], t_read: float) -> int:
         """Decode + merge archive frames, one backend ``archive_batch``.
         Runs on the executor — decoding stays off the event loop.  The
         coalesced backend call is ONE server span, parented under the first
         traced frame's wire context (one backend round, one span — exactly
-        what the client's wire span timed)."""
+        what the client's wire span timed); its ``queued_s`` runs from the
+        first frame's body being read to this thread starting the group."""
+        t_run = time.perf_counter()
         items = []
         ctx = None
         for f in frames:
@@ -361,16 +368,17 @@ class FDBServer:
             if tr.enabled:
                 sp.set("frames", len(frames))
                 sp.set("n_items", len(items))
+                sp.set("queued_s", t_run - t_read)
             self.fdb.archive_batch(items)
         return len(items)
 
-    async def _run_op(self, body: bytes, writer, wlock, conn: str) -> None:
+    async def _run_op(self, body: bytes, t_read: float, writer, wlock, conn: str) -> None:
         loop = asyncio.get_running_loop()
         req_id, opcode, _ = P.split_frame(body)
         t0 = time.perf_counter()
         try:
             payload = await loop.run_in_executor(
-                self._executor, self._serve_op, opcode, body
+                self._executor, self._serve_op, body, t_read
             )
             resp_op = Op.OK
         except asyncio.CancelledError:
@@ -386,12 +394,15 @@ class FDBServer:
         await self._send(writer, wlock, req_id, resp_op, payload)
 
     # --------------------------------------------------------- op execution
-    def _serve_op(self, opcode: int, body: bytes) -> bytes:
+    def _serve_op(self, body: bytes, t_read: float) -> bytes:
         """Decode one request frame, run it against the FDB, encode the OK
         payload.  Runs on the executor thread pool.  A TRACE_FLAG'd frame
         carries a trace-context prefix: the op executes under a server span
         parented to the client's wire span, so the client can stitch the
-        server-side time into ONE trace via the Op.TRACE round."""
+        server-side time into ONE trace via the Op.TRACE round.  The span's
+        ``queued_s`` runs from the frame's body being read (``t_read``) to
+        this thread starting the op."""
+        t_run = time.perf_counter()
         _, raw_op, cur = P.split_frame(body)
         opcode, traced = P.mask_op(raw_op)
         ctx = None
@@ -408,6 +419,7 @@ class FDBServer:
         with tr.span(_SERVER_SPANS.get(opcode, "server.op"), remote_parent=ctx) as sp:
             if tr.enabled:
                 sp.set("op", Op.NAMES.get(opcode, hex(opcode)))
+                sp.set("queued_s", t_run - t_read)
             return self._dispatch_op(opcode, cur)
 
     def _dispatch_op(self, opcode: int, cur: Cursor) -> bytes:

@@ -28,9 +28,8 @@ from repro.core import (
     wire_size,
 )
 from repro.core.codec import (
-    kernel_launches,
+    DecodedFieldSet,
     parse_header,
-    reset_kernel_launches,
     take_fields,
 )
 from repro.core.config import ConfigError
@@ -43,12 +42,20 @@ from repro.kernels.grib_pack import (
 )
 from repro.kernels.grib_pack.ref import field_stats, pack_ref
 from repro.metrics.iostats import IOStats
+from repro.obs import Tracer, install_tracer
 
 NBITS_SWEEP = (8, 16, 24)
 
 
 def temperature_fields(rng, f, h, w):
     return (rng.standard_normal((f, h, w)) * 40 + 250).astype(np.float32)
+
+
+def launches(tracer: Tracer) -> dict:
+    """Kernel launches a tracer saw: one ``codec.pack``/``codec.unpack``
+    span per launch."""
+    names = [s.name for s in tracer.spans()]
+    return {"pack": names.count("codec.pack"), "unpack": names.count("codec.unpack")}
 
 
 def example_key(**over) -> Key:
@@ -165,20 +172,46 @@ class TestWireFormat:
 class TestEncodeDecode:
     def test_one_pack_launch_per_uniform_batch(self):
         fields = temperature_fields(np.random.default_rng(7), 9, 16, 128)
-        reset_kernel_launches()
-        encode_fields(fields, nbits=16)
-        assert kernel_launches() == {"pack": 1, "unpack": 0}
+        tr = Tracer()
+        encode_fields(fields, nbits=16, tracer=tr)
+        assert launches(tr) == {"pack": 1, "unpack": 0}
 
     def test_one_launch_per_shape_group_when_ragged(self):
         rng = np.random.default_rng(8)
         ragged = [temperature_fields(rng, 1, 8, 128)[0] for _ in range(3)]
         ragged += [temperature_fields(rng, 1, 16, 128)[0] for _ in range(2)]
-        reset_kernel_launches()
-        payloads = encode_fields(ragged)
-        assert kernel_launches()["pack"] == 2
-        reset_kernel_launches()
-        decode_payloads(payloads)
-        assert kernel_launches()["unpack"] == 2
+        tr = Tracer()
+        payloads = encode_fields(ragged, tracer=tr)
+        assert launches(tr)["pack"] == 2
+        tr = Tracer()
+        decode_payloads(payloads, tracer=tr)
+        assert launches(tr)["unpack"] == 2
+
+    @pytest.mark.parametrize("kind, steps", [
+        ("pack", ["stack", "device", "frame"]),
+        ("unpack", ["stack", "device"]),
+    ])
+    def test_child_spans_nest_and_cover_the_launch(self, kind, steps):
+        fields = temperature_fields(np.random.default_rng(10), 8, 256, 512)
+        payloads = encode_fields(fields, nbits=16)
+        decode_payloads(payloads)  # both programs compiled before timing
+        tr = Tracer()
+        if kind == "pack":
+            encode_fields(fields, nbits=16, tracer=tr)
+        else:
+            decode_payloads(payloads, tracer=tr)
+        spans = tr.spans()
+        (parent,) = [s for s in spans if s.name == f"codec.{kind}"]
+        children = sorted((s for s in spans if s.parent_id == parent.span_id),
+                          key=lambda s: s.t0)
+        assert [s.name for s in children] == [f"codec.{kind}.{st}" for st in steps]
+        assert len(spans) == 1 + len(steps)
+        for s in children:
+            assert parent.t0 <= s.t0 <= s.t1 <= parent.t1
+        for a, b in zip(children, children[1:]):
+            assert a.t1 <= b.t0
+        covered = sum(s.duration_s for s in children)
+        assert covered >= 0.95 * parent.duration_s
 
     def test_decode_is_batchsplit_independent(self):
         # the lazy chunked read path must yield bit-identical floats no
@@ -285,12 +318,12 @@ class TestClientRoundTrip:
         keys, fields = self._archive(fdb, steps=4, params=2)
         req = {**dict(example_key()), "step": ["0", "1", "2", "3"], "param": ["u", "v"]}
         fs = fdb.retrieve_many(req)
-        decoded = fs.decode(chunk=2)
-        reset_kernel_launches()
+        tr = Tracer()
+        decoded = DecodedFieldSet(fs, chunk=2, tracer=tr)
         first = decoded[keys[0]]
         assert first is not None
         # touching one key decodes ONE chunk in ONE launch, not the set
-        assert kernel_launches()["unpack"] == 1
+        assert launches(tr)["unpack"] == 1
         whole = fdb.retrieve_fields(req).read_all()
         for k, a in whole.items():
             assert np.array_equal(a, decoded[k])  # chunking never changes bits
@@ -395,9 +428,10 @@ class TestCodecConfig:
             hot = example_key(number="0")
             cold = example_key(number="5")
             fields = temperature_fields(np.random.default_rng(1), 2, 8, 128)
-            reset_kernel_launches()
+            tr = Tracer()
+            install_tracer(fdb, tr)
             fdb.archive_fields([hot, cold], fields)  # ONE call, two widths
-            assert kernel_launches()["pack"] == 2  # one launch per tier
+            assert launches(tr)["pack"] == 2  # one launch per tier
             fdb.flush()
             assert parse_header(fdb.read(hot)).nbits == 16
             assert parse_header(fdb.read(cold)).nbits == 24
@@ -518,10 +552,11 @@ class TestHammerCodec:
         )
         fdb = hammer.make_backend("posix", root=str(tmp_path), codec_nbits=16)
         try:
-            reset_kernel_launches()
+            tr = Tracer()
+            install_tracer(fdb, tr)
             hammer.run_hammer(fdb, spec, "archive")
             # one grib_pack launch per (proc, output step) batch — never per field
-            assert kernel_launches()["pack"] == spec.n_procs * spec.n_steps
+            assert launches(tr)["pack"] == spec.n_procs * spec.n_steps
             w = hammer.run_hammer(fdb, spec, "archive")
         finally:
             fdb.close()

@@ -19,6 +19,7 @@ cross-process traces the ISSUE names as acceptance:
 import json
 import threading
 import tracemalloc
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -497,6 +498,64 @@ class TestStitchedTraces:
         # backend time on the server
         assert {"fdb.archive_batch", "store.archive_batch",
                 "catalogue.archive_batch"} <= names
+
+
+def traced_served_round() -> list:
+    """One archive call of four fields, a flush and a one-field retrieve
+    through a traced codec tier over a self-hosted wire, each under a root
+    span of its own (``req.archive``, ``req.flush``, ``req.retrieve``);
+    returns every span, the server's included."""
+    fdb = build_fdb({"type": "codec", "nbits": 16, "trace": True,
+                     "inner": {"type": "remote",
+                               "inner": {"backend": "daos", "schema": "nwp-daos"}}})
+    keys = [base_key(0) | {"levelist": str(lv)} for lv in range(4)]
+    fields = populate_fields(4)[1]
+    tr = fdb.tracer
+    try:
+        with tr.span("req.archive", parent=None):
+            fdb.archive_fields(keys, fields)
+        with tr.span("req.flush", parent=None):
+            fdb.flush()
+        with tr.span("req.retrieve", parent=None):
+            assert fdb.retrieve_fields(keys[1]).arrays().shape == (1, 8, 128)
+    finally:
+        fdb.close()  # hands over the server's spans
+    return tr.spans()
+
+
+class TestWireSpans:
+    def test_send_and_recv_are_children_of_the_wire_span(self):
+        spans = traced_served_round()
+        check_trace_structure(spans)
+        wire = [s for s in spans if s.name.startswith("wire.")
+                and s.name not in ("wire.send", "wire.recv")]
+        assert {"wire.archive_batch", "wire.flush", "wire.retrieve_many"} <= {
+            s.name for s in wire}
+        for w in wire:
+            kids = sorted((s for s in spans if s.parent_id == w.span_id and s.proc == "client"),
+                          key=lambda s: s.t0)
+            assert [s.name for s in kids] == ["wire.send", "wire.recv"], w.name
+            assert w.t0 <= kids[0].t0 <= kids[0].t1 <= kids[1].t0 <= kids[1].t1 <= w.t1
+            assert all(s.thread_id == w.thread_id for s in kids)
+
+    def test_traced_ops_carry_their_wait_for_a_server_thread(self):
+        spans = traced_served_round()
+        served = {s.name: s for s in spans if s.name.startswith("server.")}
+        assert {"server.archive_batch", "server.flush", "server.retrieve_many"} <= set(served)
+        for s in served.values():
+            assert s.attrs["queued_s"] >= 0.0
+        by_id = {s.span_id: s for s in spans}
+        assert by_id[served["server.retrieve_many"].parent_id].name == "wire.retrieve_many"
+
+    def test_server_spans_per_traced_request_stay_put(self):
+        """A server keeps its spans in a ring of its own until its client
+        fetches them; the spans each request leaves there are counted, so a
+        new server span cannot fill the ring unnoticed."""
+        spans = traced_served_round()
+        roots = {s.trace_id: s.name for s in spans if s.name.startswith("req.")}
+        per_request = Counter(roots[s.trace_id] for s in spans
+                              if s.proc == "server" and s.trace_id in roots)
+        assert per_request == {"req.archive": 4, "req.flush": 4, "req.retrieve": 5}
 
 
 # ---------------------------------------------------------------------------
