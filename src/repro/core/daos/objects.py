@@ -159,16 +159,16 @@ class ArrayObject:
         size = self._size
         if length is None:
             length = max(0, size - offset)
+        if len(extents) == 1 and extents[0].offset == offset and len(extents[0].data) == length:
+            return extents[0].data  # a field written once and read whole: no copy
         buf = bytearray(length)
-        filled = bytearray(length)  # visibility mask
         # later epochs win: extents list is in epoch order already
         for ext in extents:
             lo = max(offset, ext.offset)
             hi = min(offset + length, ext.end)
             if lo >= hi:
                 continue
-            buf[lo - offset : hi - offset] = ext.data[lo - ext.offset : hi - ext.offset]
-            filled[lo - offset : hi - offset] = b"\x01" * (hi - lo)
+            buf[lo - offset : hi - offset] = memoryview(ext.data)[lo - ext.offset : hi - ext.offset]
         return bytes(buf)
 
     def get_size(self) -> int:

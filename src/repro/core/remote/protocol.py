@@ -139,42 +139,54 @@ def pack_str(s: str) -> bytes:
 
 class Cursor:
     """A bounds-checked reader over one frame body; every short read is a
-    :class:`ProtocolError` naming what was expected, never a silent slice."""
+    :class:`ProtocolError` naming what was expected, never a silent slice.
+
+    It reads through a ``memoryview`` from ``pos`` on, so a frame's header
+    is skipped without copying its body; every byte string it hands out is
+    a ``bytes`` copy, so no caller holds a view into a wire buffer."""
 
     __slots__ = ("_buf", "_pos")
 
-    def __init__(self, buf: bytes):
-        self._buf = buf
-        self._pos = 0
+    def __init__(self, buf, pos: int = 0):
+        self._buf = memoryview(buf)
+        self._pos = pos
 
-    def _take(self, n: int, what: str) -> bytes:
-        end = self._pos + n
-        if end > len(self._buf):
+    def _skip(self, n: int, what: str) -> int:
+        """Advance over ``n`` bytes; returns where they start."""
+        start = self._pos
+        if start + n > len(self._buf):
             raise ProtocolError(
                 f"truncated frame: needed {n} bytes for {what} at offset "
-                f"{self._pos}, only {len(self._buf) - self._pos} left"
+                f"{start}, only {len(self._buf) - start} left"
             )
-        out = self._buf[self._pos:end]
-        self._pos = end
-        return out
+        self._pos = start + n
+        return start
+
+    def _take(self, n: int, what: str) -> bytes:
+        start = self._skip(n, what)
+        return bytes(self._buf[start:self._pos])
+
+    def _unpack(self, fmt: struct.Struct, what: str) -> int:
+        return fmt.unpack_from(self._buf, self._skip(fmt.size, what))[0]
 
     def u8(self, what: str = "u8") -> int:
-        return _U8.unpack(self._take(1, what))[0]
+        return self._unpack(_U8, what)
 
     def u16(self, what: str = "u16") -> int:
-        return _U16.unpack(self._take(2, what))[0]
+        return self._unpack(_U16, what)
 
     def u32(self, what: str = "u32") -> int:
-        return _U32.unpack(self._take(4, what))[0]
+        return self._unpack(_U32, what)
 
     def u64(self, what: str = "u64") -> int:
-        return _U64.unpack(self._take(8, what))[0]
+        return self._unpack(_U64, what)
 
     def bytes_(self, what: str = "bytes") -> bytes:
         return self._take(self.u32(f"{what} length"), what)
 
     def str_(self, what: str = "str") -> str:
-        return self.bytes_(what).decode("utf-8")
+        start = self._skip(self.u32(f"{what} length"), what)
+        return str(self._buf[start:self._pos], "utf-8")
 
     def expect_end(self) -> None:
         if self._pos != len(self._buf):
@@ -188,9 +200,10 @@ class Cursor:
 # ---------------------------------------------------------------------------
 
 def encode_frame(req_id: int, opcode: int, payload: bytes = b"") -> bytes:
-    """One complete wire frame, length prefix included."""
-    body = _HDR.pack(req_id, opcode) + payload
-    return _U32.pack(len(body)) + body
+    """One complete wire frame, length prefix included: one copy of the
+    payload, which ``bytes.join`` makes without holding the interpreter
+    lock once the frame reaches 1 MiB."""
+    return b"".join((_U32.pack(_HDR.size + len(payload)), _HDR.pack(req_id, opcode), payload))
 
 
 def frame_length(header: bytes, *, max_frame: int = DEFAULT_MAX_FRAME) -> int:
@@ -206,12 +219,14 @@ def frame_length(header: bytes, *, max_frame: int = DEFAULT_MAX_FRAME) -> int:
     return n
 
 
-def split_frame(body: bytes) -> tuple[int, int, Cursor]:
-    """(request_id, opcode, payload cursor) of one frame body."""
+def split_frame(body) -> tuple[int, int, Cursor]:
+    """(request_id, opcode, payload cursor) of one frame body (``bytes``,
+    ``bytearray`` or ``memoryview``); the cursor starts past the header
+    over the body itself, so nothing is copied."""
     if len(body) < _HDR.size:
         raise ProtocolError(f"frame body of {len(body)} bytes is too short")
     req_id, opcode = _HDR.unpack_from(body)
-    return req_id, opcode, Cursor(body[_HDR.size:])
+    return req_id, opcode, Cursor(body, _HDR.size)
 
 
 # ---------------------------------------------------------------------------

@@ -15,15 +15,27 @@ or from a shell (blocks until interrupted)::
 
 Concurrency model:
 
-- one reader coroutine per connection feeds a BOUNDED frame queue; when a
-  client pipelines more than ``max_inflight`` requests the reader stops
-  reading and TCP flow control pushes back — per-connection backpressure,
-  not unbounded buffering;
+- one event-loop thread serves every connection.  Each connection's intake
+  is an :class:`asyncio.BufferedProtocol`.  Between frames it reads into a
+  64 KiB head buffer, so a request frame arrives with its header in one
+  ``recv_into``; a larger frame gets one ``bytearray`` for its body (after
+  the ``max_frame`` check), and its body goes straight into it: one
+  ``recv_into`` takes everything the kernel holds for the frame, so a large
+  frame costs a few loop turns, not one per 256 KiB, and is never copied to
+  peel its header.  The buffer is allocated at most 16 MiB ahead of the
+  bytes received and doubles as it fills, so a header alone reserves no
+  more than that;
+- complete frames go onto a per-connection queue; when it holds
+  ``max_inflight`` frames (and whatever other frames the same read
+  completed) the intake pauses the transport and TCP flow control pushes
+  back on the client — per-connection backpressure, not unbounded
+  buffering — and taking a frame off the queue resumes it;
 - one worker coroutine per connection executes ops serially (a client's
   ``archive`` -> ``flush`` ordering survives the wire) and hands the
   blocking FDB calls to a thread pool, so connections run concurrently and
   contention lands on the backend's own locks, exactly where the paper
-  puts it;
+  puts it.  It is the connection's only writer: replies go out through
+  ``transport.write`` and wait for the transport's write buffer to drain;
 - wire-level request batching: consecutive queued ``ARCHIVE_BATCH`` frames
   are coalesced into ONE backend ``archive_batch`` call (each frame still
   gets its own response), so a bursty client amortises backend rounds the
@@ -32,6 +44,11 @@ Concurrency model:
 Per-connection wire telemetry (bytes in/out, handling time, coalesced frame
 counts, per-connection op shards) accumulates in ``wire_stats`` — an
 :class:`~repro.metrics.iostats.IOStats` like every other sink in the repo.
+``wire_frame_read`` counts the frames read, their body bytes and the seconds
+from each header to its complete body, and ``counters["wire_frame_read_calls"]``
+the ``recv_into`` calls that filled those bodies; a traced op's ``server.*``
+span carries the same for its frame as ``read_s`` and ``read_calls``, beside
+``queued_s``.
 """
 
 from __future__ import annotations
@@ -42,7 +59,7 @@ import json
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Mapping
+from typing import Mapping, NamedTuple
 
 from ...metrics.iostats import IOStats
 from ...obs.tracer import NULL_TRACER, SpanContext, Tracer, install_tracer
@@ -51,8 +68,18 @@ from .protocol import Cursor, Op, ProtocolError
 
 __all__ = ["FDBServer", "serve_fdb"]
 
-#: sentinel the reader enqueues on clean EOF so the worker drains and exits
+#: sentinel the intake enqueues on clean EOF so the worker drains and exits
 _EOF = object()
+
+#: bytes a connection reads between frames: a request frame arrives with
+#: its header in one ``recv_into``, and a larger frame's first bytes move
+#: from here into the buffer sized from its header
+_HEAD_BYTES = 1 << 16
+
+#: the most a frame's body buffer is allocated ahead of the bytes received
+#: (it doubles as it fills), so a header that promises a large body and
+#: sends none holds the server to this much
+_AHEAD_BYTES = 16 << 20
 
 #: span names per served op (precomputed — no per-op string building)
 _SERVER_SPANS = {
@@ -63,6 +90,177 @@ _SERVER_SPANS = {
     Op.FLUSH: "server.flush",
     Op.STATS: "server.stats",
 }
+
+
+class _Frame(NamedTuple):
+    """One frame body off the wire and how it was read."""
+
+    body: bytearray
+    #: when the body was complete (``time.perf_counter``)
+    t_read: float
+    #: seconds from the complete header to the complete body
+    read_s: float
+    #: ``recv_into`` calls that filled the body
+    read_calls: int
+
+
+class _Connection(asyncio.BufferedProtocol):
+    """One client connection: the frame intake, the bounded queue of
+    complete frames, and the reply path (module docstring).  The queue
+    holds :class:`_Frame` items, then :data:`_EOF` or the error that ended
+    the intake."""
+
+    def __init__(self, server: "FDBServer", name: str):
+        self.name = name
+        self._server = server
+        self._head = bytearray(_HEAD_BYTES)
+        self._body: bytearray | None = None  # the frame body being filled
+        self._need = 0  # its length, from its header
+        self._got = 0  # bytes in the head, or in the body while there is one
+        self._calls = 0
+        self._t_hdr = 0.0
+        self._frames: asyncio.Queue = asyncio.Queue()
+        self._paused = False  # reading paused by a full queue
+        self._ended = False  # no more frames: EOF, a bad frame, a lost socket
+        self._lost = False
+        self._drained: asyncio.Future | None = None  # set while writing is paused
+        self.transport: asyncio.Transport | None = None
+
+    # ------------------------------------------------------------- intake
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        self._server._serve(self)
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        body = self._body
+        if body is None:
+            return memoryview(self._head)[self._got:]
+        if self._got == len(body):
+            # full, and the frame is not: double it, up to the frame's length
+            body += bytes(min(self._need - self._got, max(self._got, _AHEAD_BYTES)))
+        return memoryview(body)[self._got:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        self._got += nbytes
+        if self._body is None:
+            self._split_head()
+            return
+        self._calls += 1
+        if self._got == self._need:
+            self._push(self._body, self._t_hdr, self._calls)
+            self._body = None
+            self._got = 0
+
+    def _split_head(self) -> None:
+        """Queue every frame complete in the head buffer.  A frame that is
+        not gets a body buffer (after the ``max_frame`` check), its bytes so
+        far are moved there, and the next ``recv_into`` goes on from them;
+        fewer than 4 bytes left over stay at the head's start."""
+        head, end, at = self._head, self._got, 0
+        t = time.perf_counter()
+        while end - at >= 4:
+            try:
+                n = P.frame_length(head[at:at + 4], max_frame=self._server._max_frame)
+            except ProtocolError as e:
+                self.transport.pause_reading()
+                self._end(e)
+                return
+            have = end - at - 4
+            if have >= n:
+                self._push(head[at + 4:at + 4 + n], t, 1)
+                at += 4 + n
+                continue
+            body = bytearray(min(n, have + _AHEAD_BYTES))
+            body[:have] = head[at + 4:end]
+            self._body, self._need, self._got = body, n, have
+            self._t_hdr, self._calls = t, int(have > 0)
+            return
+        head[:end - at] = head[at:end]
+        self._got = end - at
+
+    def _push(self, body: bytearray, t_hdr: float, calls: int) -> None:
+        t = time.perf_counter()
+        frame = _Frame(body, t, t - t_hdr, calls)
+        stats = self._server.wire_stats
+        with stats.lock:
+            stats.record(
+                "wire_frame_read", seconds=frame.read_s, nbytes_r=len(body), shard=self.name
+            )
+            stats.counters["wire_frame_read_calls"] += calls
+        self._frames.put_nowait(frame)
+        if self._frames.qsize() >= self._server._max_inflight and not self._paused:
+            # backpressure: leave the rest in the socket until the worker
+            # takes a frame, so TCP flow control holds the client back
+            self._paused = True
+            self.transport.pause_reading()
+            self._server.wire_stats.record("wire_read_paused", shard=self.name)
+
+    def eof_received(self) -> bool:
+        self._end(self._cut_short() or _EOF)
+        return True  # keep the transport open: queued ops still get replies
+
+    def connection_lost(self, exc: Exception | None) -> None:
+        self._lost = True
+        self._end(self._cut_short(exc) or _EOF)
+        if self._drained is not None and not self._drained.done():
+            self._drained.set_result(None)
+
+    def _cut_short(self, exc: Exception | None = None) -> Exception | None:
+        """The error of a stream that ended inside a frame, else None."""
+        if self._body is not None:
+            return exc or ProtocolError(
+                f"connection closed mid frame ({self._got}/{self._need} bytes)"
+            )
+        if self._got:
+            return exc or ProtocolError("connection closed mid frame header")
+        return None
+
+    def _end(self, item) -> None:
+        if not self._ended:
+            self._ended = True
+            self._frames.put_nowait(item)
+
+    async def next_frame(self):
+        """The next queued item, waiting for one."""
+        item = await self._frames.get()
+        self._took()
+        return item
+
+    def next_frame_nowait(self):
+        """The next queued item; :class:`asyncio.QueueEmpty` if none."""
+        item = self._frames.get_nowait()
+        self._took()
+        return item
+
+    def _took(self) -> None:
+        if self._paused and self._frames.qsize() < self._server._max_inflight:
+            self._paused = False
+            if not self._ended:
+                self.transport.resume_reading()
+
+    # -------------------------------------------------------------- replies
+    def pause_writing(self) -> None:
+        self._drained = asyncio.get_running_loop().create_future()
+
+    def resume_writing(self) -> None:
+        if self._drained is not None and not self._drained.done():
+            self._drained.set_result(None)
+        self._drained = None
+
+    async def send(self, req_id: int, opcode: int, payload: bytes) -> None:
+        """Write one reply frame, then wait while the transport's write
+        buffer is above its high-water mark.  One coroutine of the
+        connection writes at a time, so one waiter is enough."""
+        if self._lost:
+            raise ConnectionResetError("connection lost")
+        self.transport.write(P.encode_frame(req_id, opcode, payload))
+        if self._drained is not None:
+            await self._drained
+        if self._lost:
+            raise ConnectionResetError("connection lost")
+
+    def close(self) -> None:
+        self.transport.close()
 
 
 class FDBServer:
@@ -170,7 +368,9 @@ class FDBServer:
     async def _main(self) -> None:
         self._loop = asyncio.get_running_loop()
         self._stop_ev = asyncio.Event()
-        server = await asyncio.start_server(self._on_connect, self._host, self._port)
+        server = await self._loop.create_server(
+            lambda: _Connection(self, f"conn{next(self._conn_ids)}"), self._host, self._port
+        )
         sock = server.sockets[0].getsockname()
         self.addr = (sock[0], sock[1])
         self._started.set()
@@ -178,51 +378,31 @@ class FDBServer:
             await self._stop_ev.wait()
         finally:
             server.close()
-            await server.wait_closed()
+            # close every connection first: wait_closed waits until every
+            # one has been dropped
             for t in list(self._conn_tasks):
                 t.cancel()
             await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+            await server.wait_closed()
 
     # ----------------------------------------------------------- connections
-    async def _on_connect(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter) -> None:
-        task = asyncio.current_task()
-        assert task is not None
+    def _serve(self, conn: _Connection) -> None:
+        task = asyncio.get_running_loop().create_task(self._serve_conn(conn))
         self._conn_tasks.add(task)
-        conn = f"conn{next(self._conn_ids)}"
-        wlock = asyncio.Lock()
+        task.add_done_callback(self._conn_tasks.discard)
+
+    async def _serve_conn(self, conn: _Connection) -> None:
         try:
-            await self._handshake(reader, writer, wlock, conn)
-            # bounded frame queue: the reader below stops pulling off the
-            # socket once max_inflight frames are pending, so TCP flow
-            # control is the backpressure all the way to the client.  Each
-            # frame rides with the moment its body was read, so a traced op
-            # can tell how long it waited for a server thread
-            q: asyncio.Queue = asyncio.Queue(maxsize=self._max_inflight)
-            worker = asyncio.create_task(self._conn_worker(q, writer, wlock, conn))
-            try:
-                while True:
-                    body = await self._read_frame(reader)
-                    if body is None:
-                        break
-                    await q.put((body, time.perf_counter()))
-            finally:
-                await q.put(_EOF)
-                await worker
+            await self._handshake(conn)
+            await self._conn_worker(conn)
         except (ProtocolError, ConnectionError, OSError) as e:
-            self.wire_stats.record("wire_conn_error", shard=conn)
+            self.wire_stats.record("wire_conn_error", shard=conn.name)
             try:
-                async with wlock:
-                    writer.write(P.encode_frame(0, Op.ERR, P.encode_error(e)))
-                    await writer.drain()
+                await conn.send(0, Op.ERR, P.encode_error(e))
             except (ConnectionError, OSError):
                 pass
         finally:
-            self._conn_tasks.discard(task)
-            writer.close()
-            try:
-                await writer.wait_closed()
-            except (ConnectionError, OSError):
-                pass
+            conn.close()
 
     def _ensure_tracer(self) -> None:
         """Switch the server-side tracer on (idempotent).  Installs it down
@@ -237,11 +417,13 @@ class FDBServer:
             install_tracer(self.fdb, tracer)
             self.tracer = tracer
 
-    async def _handshake(self, reader, writer, wlock, conn: str) -> None:
-        body = await self._read_frame(reader)
-        if body is None:
+    async def _handshake(self, conn: _Connection) -> None:
+        item = await conn.next_frame()
+        if item is _EOF:
             raise ConnectionError("peer closed before handshake")
-        req_id, opcode, cur = P.split_frame(body)
+        if isinstance(item, Exception):
+            raise item
+        req_id, opcode, cur = P.split_frame(item.body)
         if opcode != Op.HELLO:
             raise ProtocolError(
                 f"expected HELLO, got opcode {Op.NAMES.get(opcode, opcode)!r}"
@@ -256,74 +438,50 @@ class FDBServer:
             # echo the extension level as an optional trailing u16 a v1
             # client never reads — only a peer that advertised it gets it
             payload += P.pack_u16(P.TRACE_EXT_VERSION)
-        await self._send(writer, wlock, req_id, Op.OK, payload)
-        self.wire_stats.record("wire_hello", nbytes_r=len(body), shard=conn)
-
-    async def _read_frame(self, reader: asyncio.StreamReader) -> bytes | None:
-        try:
-            hdr = await reader.readexactly(4)
-        except asyncio.IncompleteReadError as e:
-            if not e.partial:
-                return None  # clean EOF between frames
-            raise ProtocolError("connection closed mid frame header") from e
-        except ConnectionError:
-            return None
-        n = P.frame_length(hdr, max_frame=self._max_frame)
-        try:
-            return await reader.readexactly(n)
-        except asyncio.IncompleteReadError as e:
-            raise ProtocolError(
-                f"connection closed mid frame ({len(e.partial)}/{n} bytes)"
-            ) from e
-
-    async def _send(self, writer, wlock, req_id: int, opcode: int, payload: bytes) -> None:
-        frame = P.encode_frame(req_id, opcode, payload)
-        async with wlock:
-            writer.write(frame)
-            await writer.drain()
+        await conn.send(req_id, Op.OK, payload)
+        self.wire_stats.record("wire_hello", shard=conn.name)
 
     # ---------------------------------------------------------------- worker
-    async def _conn_worker(self, q: asyncio.Queue, writer, wlock, conn: str) -> None:
+    async def _conn_worker(self, conn: _Connection) -> None:
         """Serial op execution for one connection (ordering survives the
-        wire), with greedy coalescing of consecutive archive frames."""
+        wire), with greedy coalescing of consecutive archive frames; the
+        error that ended the intake is raised once the frames before it
+        are answered."""
         pending = None
         while True:
-            item = pending if pending is not None else await q.get()
+            item = pending if pending is not None else await conn.next_frame()
             pending = None
             if item is _EOF:
                 return
-            body, t_read = item
-            req_id, opcode, _ = P.split_frame(body)
-            if P.mask_op(opcode)[0] == Op.ARCHIVE_BATCH:
+            if isinstance(item, Exception):
+                raise item
+            if _base_op(item) == Op.ARCHIVE_BATCH:
                 # wire-level batching: drain whatever archive frames are
                 # already queued into one backend round (the TRACE_FLAG bit
                 # is per-frame — masked off before comparing opcodes)
-                frames = [body]
+                frames = [item]
                 while len(frames) < self._coalesce:
                     try:
-                        nxt = q.get_nowait()
+                        nxt = conn.next_frame_nowait()
                     except asyncio.QueueEmpty:
                         break
-                    if nxt is _EOF or P.mask_op(P.split_frame(nxt[0])[1])[0] != Op.ARCHIVE_BATCH:
+                    if not isinstance(nxt, _Frame) or _base_op(nxt) != Op.ARCHIVE_BATCH:
                         pending = nxt
                         break
-                    frames.append(nxt[0])
-                await self._run_archive_group(frames, t_read, writer, wlock, conn)
+                    frames.append(nxt)
+                await self._run_archive_group(frames, conn)
                 continue
             try:
-                await self._run_op(body, t_read, writer, wlock, conn)
+                await self._run_op(item, conn)
             except (ConnectionError, OSError):
                 return  # peer gone: nothing left to answer
 
-    async def _run_archive_group(
-        self, frames: list[bytes], t_read: float, writer, wlock, conn: str
-    ) -> None:
+    async def _run_archive_group(self, frames: list[_Frame], conn: _Connection) -> None:
         loop = asyncio.get_running_loop()
         t0 = time.perf_counter()
         try:
-            nbytes_in = sum(len(f) for f in frames)
             merged = await loop.run_in_executor(
-                self._executor, self._archive_frames, frames, t_read
+                self._executor, self._archive_frames, frames
             )
             err = None
         except asyncio.CancelledError:
@@ -332,30 +490,30 @@ class FDBServer:
             merged, err = 0, e
         dt = time.perf_counter() - t0
         self.wire_stats.record(
-            "wire_archive_batch", seconds=dt, nbytes_r=nbytes_in, shard=conn,
-            count=merged or 1,
+            "wire_archive_batch", seconds=dt, shard=conn.name, count=merged or 1,
         )
         if len(frames) > 1:
-            self.wire_stats.record("wire_coalesced_frames", count=len(frames), shard=conn)
+            self.wire_stats.record("wire_coalesced_frames", count=len(frames), shard=conn.name)
         for f in frames:
-            req_id, _, _ = P.split_frame(f)
+            req_id = P.split_frame(f.body)[0]
             if err is None:
-                await self._send(writer, wlock, req_id, Op.OK, b"")
+                await conn.send(req_id, Op.OK, b"")
             else:
-                await self._send(writer, wlock, req_id, Op.ERR, P.encode_error(err))
+                await conn.send(req_id, Op.ERR, P.encode_error(err))
 
-    def _archive_frames(self, frames: list[bytes], t_read: float) -> int:
+    def _archive_frames(self, frames: list[_Frame]) -> int:
         """Decode + merge archive frames, one backend ``archive_batch``.
         Runs on the executor — decoding stays off the event loop.  The
         coalesced backend call is ONE server span, parented under the first
         traced frame's wire context (one backend round, one span — exactly
         what the client's wire span timed); its ``queued_s`` runs from the
-        first frame's body being read to this thread starting the group."""
+        first frame's body being read to this thread starting the group,
+        and its ``read_s``/``read_calls`` are the first frame's read."""
         t_run = time.perf_counter()
         items = []
         ctx = None
         for f in frames:
-            _, opcode, cur = P.split_frame(f)
+            _, opcode, cur = P.split_frame(f.body)
             traced = P.mask_op(opcode)[1]
             if traced:
                 tid, sid = P.decode_trace_ctx(cur)
@@ -366,20 +524,21 @@ class FDBServer:
         tr = self.tracer
         with tr.span("server.archive_batch", remote_parent=ctx) as sp:
             if tr.enabled:
+                first = frames[0]
                 sp.set("frames", len(frames))
                 sp.set("n_items", len(items))
-                sp.set("queued_s", t_run - t_read)
+                sp.set("queued_s", t_run - first.t_read)
+                sp.set("read_s", first.read_s)
+                sp.set("read_calls", first.read_calls)
             self.fdb.archive_batch(items)
         return len(items)
 
-    async def _run_op(self, body: bytes, t_read: float, writer, wlock, conn: str) -> None:
+    async def _run_op(self, frame: _Frame, conn: _Connection) -> None:
         loop = asyncio.get_running_loop()
-        req_id, opcode, _ = P.split_frame(body)
+        req_id, opcode, _ = P.split_frame(frame.body)
         t0 = time.perf_counter()
         try:
-            payload = await loop.run_in_executor(
-                self._executor, self._serve_op, body, t_read
-            )
+            payload = await loop.run_in_executor(self._executor, self._serve_op, frame)
             resp_op = Op.OK
         except asyncio.CancelledError:
             raise
@@ -389,21 +548,21 @@ class FDBServer:
         base = P.mask_op(opcode)[0]
         self.wire_stats.record(
             f"wire_{Op.NAMES.get(base, hex(base))}",
-            seconds=dt, nbytes_r=len(body), nbytes_w=len(payload), shard=conn,
+            seconds=dt, nbytes_w=len(payload), shard=conn.name,
         )
-        await self._send(writer, wlock, req_id, resp_op, payload)
+        await conn.send(req_id, resp_op, payload)
 
     # --------------------------------------------------------- op execution
-    def _serve_op(self, body: bytes, t_read: float) -> bytes:
+    def _serve_op(self, frame: _Frame) -> bytes:
         """Decode one request frame, run it against the FDB, encode the OK
         payload.  Runs on the executor thread pool.  A TRACE_FLAG'd frame
         carries a trace-context prefix: the op executes under a server span
         parented to the client's wire span, so the client can stitch the
         server-side time into ONE trace via the Op.TRACE round.  The span's
-        ``queued_s`` runs from the frame's body being read (``t_read``) to
-        this thread starting the op."""
+        ``queued_s`` runs from the frame's body being read to this thread
+        starting the op; ``read_s`` and ``read_calls`` are that read."""
         t_run = time.perf_counter()
-        _, raw_op, cur = P.split_frame(body)
+        _, raw_op, cur = P.split_frame(frame.body)
         opcode, traced = P.mask_op(raw_op)
         ctx = None
         if traced:
@@ -419,7 +578,9 @@ class FDBServer:
         with tr.span(_SERVER_SPANS.get(opcode, "server.op"), remote_parent=ctx) as sp:
             if tr.enabled:
                 sp.set("op", Op.NAMES.get(opcode, hex(opcode)))
-                sp.set("queued_s", t_run - t_read)
+                sp.set("queued_s", t_run - frame.t_read)
+                sp.set("read_s", frame.read_s)
+                sp.set("read_calls", frame.read_calls)
             return self._dispatch_op(opcode, cur)
 
     def _dispatch_op(self, opcode: int, cur: Cursor) -> bytes:
@@ -463,6 +624,11 @@ class FDBServer:
         if opcode == Op.HELLO:
             raise ProtocolError("duplicate handshake on an established connection")
         raise ProtocolError(f"unknown opcode {opcode:#x}")
+
+
+def _base_op(frame: _Frame) -> int:
+    """A frame's opcode without the TRACE_FLAG bit."""
+    return P.mask_op(P.split_frame(frame.body)[1])[0]
 
 
 def serve_fdb(fdb, *, host: str = "127.0.0.1", port: int = 0, **kw) -> FDBServer:

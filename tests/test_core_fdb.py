@@ -184,6 +184,16 @@ class TestDaosEmulation:
         assert arr.read(0, 8) == b"aaaabbbb"
         assert arr.get_size() == 8
 
+    def test_array_read_of_one_extent(self):
+        from repro.core.daos.objects import ArrayObject, ObjectId
+
+        arr = ArrayObject(ObjectId(1, 2))
+        arr.write(0, bytearray(b"0123456789"))
+        assert arr.read() == arr.read(0, 10) == b"0123456789"
+        assert type(arr.read()) is bytes
+        assert arr.read(3, 4) == b"3456"
+        assert arr.read(8, 4) == b"89\x00\x00"
+
     def test_oid_ranges_do_not_collide_across_threads(self):
         eng = DaosEngine()
         eng.create_pool("p")

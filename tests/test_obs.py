@@ -547,6 +547,23 @@ class TestWireSpans:
         by_id = {s.span_id: s for s in spans}
         assert by_id[served["server.retrieve_many"].parent_id].name == "wire.retrieve_many"
 
+    def test_traced_ops_carry_their_frame_read(self):
+        spans = traced_served_round()
+        served = [s for s in spans if s.name.startswith("server.")]
+        assert "server.archive_batch" in {s.name for s in served}
+        for s in served:
+            assert s.attrs["read_s"] >= 0.0 and s.attrs["read_calls"] >= 1, s.name
+
+    def test_untraced_server_counts_its_frame_reads(self, tmp_path):
+        with build_fdb({"type": "remote",
+                        "inner": {"backend": "posix", "root": str(tmp_path / "w")}}) as fdb:
+            fdb.archive(base_key(), b"r" * 4096)
+            fdb.flush()
+            wire = fdb.server_stats()["wire"]
+        assert wire["ops"]["wire_frame_read"] >= 3  # hello, archive, flush
+        assert wire["op_bytes_r"]["wire_frame_read"] > 4096
+        assert wire["op_time"]["wire_frame_read"] >= 0.0
+
     def test_server_spans_per_traced_request_stay_put(self):
         """A server keeps its spans in a ring of its own until its client
         fetches them; the spans each request leaves there are counted, so a

@@ -8,15 +8,21 @@ config node, and — by subclassing the equivalence suite from
 ``test_select`` — the property that a SelectFDB tree with one remote tier
 is observationally identical to the bare backend.
 
+The server's frame intake is driven by raw sockets: frames fed in pieces,
+frames larger than the socket buffers, oversized and cut-short frames, and
+pipelining past the per-connection bound.
+
 Plus the satellite regression: a FieldSet fetch returning the wrong number
 of handles fails loudly naming the keys (it used to zip short and leave
 unresolved sentinels behind), which matters once fetches cross a network
 hop.
 """
 
+import os
 import socket
 import threading
 import time
+import tracemalloc
 
 import pytest
 
@@ -72,6 +78,7 @@ def connect(server: FDBServer, **kw) -> RemoteFDB:
 class TestProtocol:
     def test_frame_roundtrip(self):
         frame = P.encode_frame(7, P.Op.FLUSH, b"xyz")
+        assert frame == b"\x00\x00\x00\x08" + b"\x00\x00\x00\x07" + bytes([P.Op.FLUSH]) + b"xyz"
         n = P.frame_length(frame[:4])
         assert n == len(frame) - 4
         req_id, opcode, cur = P.split_frame(frame[4:])
@@ -128,6 +135,29 @@ class TestProtocol:
     def test_remote_timeout_is_both_remote_error_and_timeout(self):
         e = RemoteTimeout("too slow")
         assert isinstance(e, RemoteError) and isinstance(e, TimeoutError)
+
+    @pytest.mark.parametrize("wrap", [bytes, bytearray, memoryview])
+    def test_split_frame_reads_any_buffer_alike(self, wrap):
+        items = [(ident(step=str(s)), bytes([s]) * 5) for s in range(3)]
+        body = P.encode_frame(7, P.Op.ARCHIVE_BATCH, P.encode_archive_batch(items))[4:]
+        req_id, opcode, cur = P.split_frame(wrap(bytearray(body)))
+        assert (req_id, opcode) == (7, P.Op.ARCHIVE_BATCH)
+        assert P.decode_archive_batch(cur) == items
+        cur.expect_end()
+
+    def test_decoded_payloads_are_bytes_that_outlive_their_buffer(self):
+        items = [(ident(step=str(s)), bytes([65 + s]) * 9) for s in range(3)]
+        handles = [b"abc", None, b"defg"]
+        for encoded, decode, want in (
+            (P.encode_archive_batch(items), P.decode_archive_batch, items),
+            (P.encode_handles(handles), P.decode_handles, handles),
+        ):
+            buf = bytearray(P.encode_frame(1, P.Op.OK, encoded)[4:])
+            got = decode(P.split_frame(buf)[2])
+            buf[:] = b"\xff" * len(buf)  # the wire buffer is reused
+            assert got == want
+            payloads = [p[1] if isinstance(p, tuple) else p for p in got]
+            assert all(p is None or type(p) is bytes for p in payloads)
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +464,166 @@ class TestWireBatching:
         if data:
             _, op, cur = P.split_frame(data[4:])
             assert op == P.Op.ERR
+
+
+def _hello_frame() -> bytes:
+    return P.encode_frame(0, P.Op.HELLO, P.encode_hello())
+
+
+def _trickle(sock, data: bytes, sizes) -> None:
+    """Send ``data`` in pieces of the sizes given (the last one repeated),
+    each flushed on its own, so the server sees them in separate reads."""
+    sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+    at, i = 0, 0
+    while at < len(data):
+        n = sizes[min(i, len(sizes) - 1)]
+        sock.sendall(data[at:at + n])
+        at += n
+        i += 1
+        time.sleep(0.0005)
+
+
+def _recv_frame(sock):
+    buf = b""
+    while len(buf) < 4:
+        chunk = sock.recv(4 - len(buf))
+        if not chunk:
+            return None
+        buf += chunk
+    n = P.frame_length(buf)
+    body = b""
+    while len(body) < n:
+        body += sock.recv(n - len(body))
+    return P.split_frame(body)
+
+
+class TestFrameIntake:
+    """The server reads each frame into one buffer sized from its header;
+    whatever the reads' sizes, frames come out whole and in order."""
+
+    @pytest.mark.parametrize("sizes", [
+        [1],
+        [3, 5, 1, 2, 7, 11, 4093, 13, 1 << 16],
+        # a whole frame and the first 3 bytes of the next one's header
+        [len(_hello_frame()) + 3, 1 << 16],
+    ], ids=["1-byte", "odd", "straddling"])
+    def test_frames_fed_in_pieces_are_answered(self, tmp_path, servers, sizes):
+        server = start_server(servers, "posix", tmp_path)
+        items = [(ident(step=str(s), param=p), bytes(range(256)) * (s + 1))
+                 for s in range(2) for p in ("2t", "10u")]
+        stream = (_hello_frame()
+                  + P.encode_frame(5, P.Op.ARCHIVE_BATCH, P.encode_archive_batch(items))
+                  + P.encode_frame(6, P.Op.FLUSH))
+        sock = socket.create_connection(server.addr, timeout=30)
+        try:
+            _trickle(sock, stream, sizes)
+            assert [_recv_frame(sock)[:2] for _ in range(3)] == [
+                (0, P.Op.OK), (5, P.Op.OK), (6, P.Op.OK)]
+        finally:
+            sock.close()
+        with connect(server) as fdb:
+            for key, data in items:
+                assert fdb.read(key) == data
+        assert server.wire_stats.snapshot()["ops"]["wire_frame_read"] >= 3
+
+    def test_frame_larger_than_socket_buffers_round_trips(self, tmp_path, servers):
+        server = start_server(servers, "daos", tmp_path)
+        data = os.urandom(48 << 20)
+        with connect(server, pool_size=1) as fdb:
+            fdb.archive(ident(), data)
+            fdb.flush()
+            assert fdb.read(ident()) == data
+        snap = server.wire_stats.snapshot()
+        assert snap["op_bytes_r"]["wire_frame_read"] > len(data)
+
+    def test_oversized_length_is_refused_without_allocating(self, tmp_path, servers):
+        server = start_server(servers, "posix", tmp_path)
+        raw = _RawClient(server.addr)
+        tracemalloc.start()
+        try:
+            raw.sock.sendall((0xFFFFFFFF).to_bytes(4, "big"))
+            _, op, cur = raw.recv()
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+            raw.close()
+        assert op == P.Op.ERR
+        err = P.decode_error(cur)
+        assert err.remote_type == "ProtocolError" and "exceeds" in str(err)
+        assert peak < 16 << 20
+        assert server.wire_stats.snapshot()["ops"]["wire_conn_error"] == 1
+
+    @pytest.mark.parametrize("hello", [False, True], ids=["before-hello", "after-hello"])
+    def test_a_header_alone_reserves_little(self, tmp_path, servers, hello):
+        """A header that promises a body just under ``max_frame`` and sends
+        100 bytes of it holds the server to a buffer that grows with the
+        bytes received, not one of the promised size."""
+        server = start_server(servers, "posix", tmp_path)
+        sock = socket.create_connection(server.addr, timeout=30)
+        if hello:
+            sock.sendall(_hello_frame())
+            assert _recv_frame(sock)[:2] == (0, P.Op.OK)
+        n = P.DEFAULT_MAX_FRAME - 1
+        tracemalloc.start()
+        try:
+            sock.sendall(n.to_bytes(4, "big") + b"\0" * 100)
+            sock.shutdown(socket.SHUT_WR)
+            req_id, op, cur = _recv_frame(sock)
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+            sock.close()
+        assert (req_id, op) == (0, P.Op.ERR)
+        assert f"(100/{n} bytes)" in str(P.decode_error(cur))
+        assert peak < 40 << 20
+
+    def test_frame_counters_count_frames_and_reads_apart(self, tmp_path, servers):
+        server = start_server(servers, "posix", tmp_path)
+        sock = socket.create_connection(server.addr, timeout=30)
+        try:
+            frame = P.encode_frame(7, P.Op.FLUSH)
+            _trickle(sock, _hello_frame() + frame, [len(_hello_frame()), 1])
+            assert [_recv_frame(sock)[:2] for _ in range(2)] == [(0, P.Op.OK), (7, P.Op.OK)]
+        finally:
+            sock.close()
+        snap = server.wire_stats.snapshot()
+        assert snap["ops"]["wire_frame_read"] == 2
+        # one read or more a frame, and no more than the flush's body bytes
+        # (sent a byte at a time) and the hello's one
+        assert 2 <= snap["counters"]["wire_frame_read_calls"] <= 1 + len(frame) - 4
+        assert snap["op_bytes_r"]["wire_frame_read"] == len(_hello_frame()) + len(frame) - 8
+
+    @pytest.mark.parametrize("cut, what", [(2, "mid frame header"), (4 + 100, "(100/")],
+                             ids=["header", "body"])
+    def test_eof_inside_a_frame_is_a_clean_error(self, tmp_path, servers, cut, what):
+        server = start_server(servers, "posix", tmp_path)
+        raw = _RawClient(server.addr)
+        frame = P.encode_frame(3, P.Op.ARCHIVE_BATCH,
+                               P.encode_archive_batch([(ident(), b"z" * 1000)]))
+        raw.sock.sendall(frame[:cut])
+        raw.sock.shutdown(socket.SHUT_WR)
+        req_id, op, cur = raw.recv()
+        raw.close()
+        assert (req_id, op) == (0, P.Op.ERR)
+        err = P.decode_error(cur)
+        assert err.remote_type == "ProtocolError" and what in str(err)
+        assert server.wire_stats.snapshot()["ops"]["wire_conn_error"] == 1
+        with connect(server) as fdb:  # the server serves on
+            fdb.flush()
+
+    def test_pipelined_frames_past_the_bound_pause_reading(self, tmp_path, servers):
+        server = start_server(servers, "posix", tmp_path, max_inflight=2)
+        real_list = server.fdb.list
+        server.fdb.list = lambda req: (time.sleep(0.01), real_list(req))[1]
+        raw = _RawClient(server.addr)
+        n = 24
+        for i in range(n):
+            raw.send(100 + i, P.Op.LIST, P.encode_request(Request({"step": str(i)})))
+        got = [raw.recv()[:2] for _ in range(n)]
+        raw.close()
+        del server.fdb.list
+        assert got == [(100 + i, P.Op.OK) for i in range(n)]
+        assert server.wire_stats.snapshot()["ops"]["wire_read_paused"] >= 1
 
 
 # ---------------------------------------------------------------------------
