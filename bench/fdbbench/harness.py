@@ -16,6 +16,7 @@ import sys
 import tempfile
 import threading
 import time
+import traceback
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -60,10 +61,65 @@ TRACE_CAPACITY = 1 << 21
 
 def _server_ring() -> int:
     """Spans an in-process server's tracer holds: it builds a default
-    ``Tracer``, and hands them over when its wire client closes."""
+    ``Tracer``, and hands them over on each ``fetch_server_trace``."""
     from repro.obs import Tracer
 
     return inspect.signature(Tracer).parameters["capacity"].default
+
+
+#: seconds between two fetches of the servers' spans in a traced run
+FETCH_S = 2.0
+
+
+def _wire_clients(fdb) -> list:
+    """The wire clients of a tree: its nodes that fetch their server's
+    spans, reached through the child attributes the program's
+    ``install_tracer`` walks (``inner``, ``fdb``, ``tiers``, ``lanes``)."""
+    found, stack, seen = [], [fdb], set()
+    while stack:
+        node = stack.pop()
+        if node is None or id(node) in seen:
+            continue
+        seen.add(id(node))
+        if hasattr(node, "fetch_server_trace"):
+            found.append(node)
+        stack += [getattr(node, a, None) for a in ("inner", "fdb")]
+        stack += [*(getattr(node, "tiers", None) or ()), *(getattr(node, "lanes", None) or ())]
+    return found
+
+
+class _ServerSpans:
+    """Fetches every wire client's server spans into the tree's tracer each
+    :data:`FETCH_S` seconds while the window runs, so that no server's ring
+    fills however many requests the window serves; ``most`` is the most
+    spans one fetch brought."""
+
+    def __init__(self, fdb):
+        self.clients = _wire_clients(fdb)
+        self.most = 0
+        self.error: str | None = None
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True, name="bench-server-spans")
+
+    def fetch(self) -> None:
+        for c in self.clients:
+            self.most = max(self.most, c.fetch_server_trace())
+
+    def _run(self) -> None:
+        while not self._stop.wait(FETCH_S):
+            try:
+                self.fetch()
+            except Exception:  # noqa: BLE001 -- reported after the window
+                self.error = traceback.format_exc()
+                return
+
+    def __enter__(self) -> "_ServerSpans":
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._stop.set()
+        self._thread.join()
 
 
 def _rss_bytes() -> int:
@@ -222,7 +278,8 @@ def measure(cell: Cell, seed: int, seconds: float, *, trace: bool, devices: list
             lowerings = _lowerings()
             setup_s = time.perf_counter() - t_start
             lowered = lowerings.count
-            with _RssSampler() as rss:
+            fetching = _ServerSpans(fdb) if trace else contextlib.nullcontext()
+            with _RssSampler() as rss, fetching:
                 t_open = time.perf_counter()
                 loop.run(seconds)
                 t_close = time.perf_counter()
@@ -232,18 +289,21 @@ def measure(cell: Cell, seed: int, seconds: float, *, trace: bool, devices: list
                 loop.annotate = lambda name: contextlib.nullcontext()
             peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices)
             loop.readback()
+            if trace:
+                fetching.fetch()
         finally:
             fdb.close()
         spans = device_trace = None
         if trace:
             from . import xtrace
 
-            every = tracer.spans()
-            if sum(s.proc == "server" for s in every) >= _server_ring():
-                raise RuntimeError(f"a server's trace ring ({_server_ring()} spans) filled up: spans "
-                                   "of the window may be lost, and the wire's self time would take "
-                                   "in the server's; the program has to keep more")
-            spans = [s.to_dict() for s in every if s.t0 >= t_open and s.t1 <= t_close]
+            if fetching.error:
+                raise RuntimeError(f"fetching the servers' spans failed:\n{fetching.error}")
+            if fetching.most >= _server_ring():
+                raise RuntimeError(f"a server's trace ring ({_server_ring()} spans) filled up between "
+                                   f"two fetches {FETCH_S} s apart: spans of the window may be lost, "
+                                   "and the wire's self time would take in the server's")
+            spans = [s.to_dict() for s in tracer.spans() if s.t0 >= t_open and s.t1 <= t_close]
 
             found = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
             if not found:

@@ -11,7 +11,10 @@ and a configuration's key space (``bench/configs/<config>.json``):
   a new forecast cycle (a new ``date`` dataset), and once it holds
   ``cycles`` cycles it wipes its oldest, as a hot tier rolls its
   retention.  Each writer owns its own datasets, so a wipe never takes
-  another member's fields; wipes fall between requests;
+  another member's fields; wipes fall between requests.  With
+  ``writers.window`` ``false`` (default ``true``) the writers archive their
+  prefill in set-up, which readers and the read-back draw from, and start
+  no thread in the window: a mix of readers alone over a finished step;
 - each reader retrieves from the newest flushed step of a member (taken in
   turn, or drawn uniformly): one parameter at every level, or one field by
   exact key, through ``retrieve_fields(...).arrays()``.  A cycle is never
@@ -103,6 +106,8 @@ class Plan:
         w, r, keep = traffic["writers"], traffic["readers"], traffic["retention"]
         self.writers = [int(m) for m in w["members"]]
         self.fields_per_call = int(w["fields_per_call"])
+        #: whether the writers archive in the window, or only prefill
+        self.write_in_window = w.get("window", True)
         self.steps_per_cycle = int(keep["steps_per_cycle"])
         self.cycles = int(keep["cycles"])
         self.n_readers = int(r["count"])
@@ -120,6 +125,10 @@ class Plan:
         if self.step_size % self.fields_per_call:
             raise ValueError(f"a step of {self.step_size} fields is not whole calls of "
                              f"{self.fields_per_call}")
+        if not isinstance(self.write_in_window, bool):
+            raise ValueError(f"writers.window is true or false, not {self.write_in_window!r}")
+        if not (self.write_in_window or self.n_readers):
+            raise ValueError("a window with neither writers nor readers measures nothing")
         if self.member_pick not in ("alternate", "uniform"):
             raise ValueError(f"unknown reader member pick {self.member_pick!r}")
         if self.request not in ("param_levels", "one_field"):
@@ -372,8 +381,9 @@ class Loop:
         p = self.plan
         go = threading.Event()
         deadline = time.perf_counter() + seconds
+        writers = enumerate(p.writers) if p.write_in_window else ()
         threads = [threading.Thread(target=self._writer, args=(m, w, deadline, go), daemon=True,
-                                    name=f"bench-writer-{m}") for w, m in enumerate(p.writers)]
+                                    name=f"bench-writer-{m}") for w, m in writers]
         threads += [threading.Thread(target=self._reader, args=(r, deadline, go), daemon=True,
                                      name=f"bench-reader-{r}") for r in range(p.n_readers)]
         for t in threads:

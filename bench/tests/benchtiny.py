@@ -3,8 +3,9 @@
 ``make_root`` lays out ``BENCHMARK.json`` and ``bench/`` in a directory of
 the test's own: the harness, readers and peaks of this checkout, and one
 tiny configuration and traffic mix per kind of cell (a tiered pair of
-served codec tiers, and one served DAOS tier), with a ``cpu`` entry in the
-peaks so the harness runs on JAX's CPU backend.
+served codec tiers, and one served DAOS tier, each under writers and
+readers and under one side alone), with a ``cpu`` entry in the peaks so
+the harness runs on JAX's CPU backend.
 """
 
 from __future__ import annotations
@@ -59,6 +60,18 @@ def traffic(writers: list[int], fpc: int, readers: int, request: str, pick: str)
     }
 
 
+def readers_only(mix: dict) -> dict:
+    """``mix`` with its writers archiving the prefill alone, as
+    ``hammer-read`` is ``hammer-wr``."""
+    return {**mix, "writers": {**mix["writers"], "window": False}}
+
+
+def writers_only(mix: dict) -> dict:
+    """``mix`` with no readers and two read-backs a member, as
+    ``ens-archive`` is ``ens-wr``."""
+    return {**mix, "readers": {**mix["readers"], "count": 0}, "readback_per_member": 2}
+
+
 def manifest() -> dict:
     """The checkout's BENCHMARK.json, its cells and configurations pointed
     at the tiny files."""
@@ -73,6 +86,10 @@ def manifest() -> dict:
     m["workloads"] = [
         {"name": "ens-0p1.wr", "config": "tiny-tiered", "traffic": "tiny-wr", "chips": 1, "why": "test"},
         {"name": "hammer-1mib.wr", "config": "tiny-daos", "traffic": "tiny-hammer", "chips": 1,
+         "why": "test"},
+        {"name": "hammer-1mib.read", "config": "tiny-daos", "traffic": "tiny-hammer-read", "chips": 1,
+         "why": "test"},
+        {"name": "ens-0p1.archive", "config": "tiny-tiered", "traffic": "tiny-archive", "chips": 1,
          "why": "test"},
     ]
     return m
@@ -98,6 +115,10 @@ def make_root(tmp: Path) -> Path:
                config(SINGLE, [0, 1], {"130": [250.0, 20.0], "133": [0.004, 0.002]}, [0, 1]))
     write_json(bench / "traffic" / "tiny-wr.json", traffic([0, 1], 2, 2, "param_levels", "alternate"))
     write_json(bench / "traffic" / "tiny-hammer.json", traffic([0, 1], 2, 2, "one_field", "uniform"))
+    write_json(bench / "traffic" / "tiny-hammer-read.json", readers_only(
+        traffic([0, 1], 2, 2, "one_field", "uniform")))
+    write_json(bench / "traffic" / "tiny-archive.json", writers_only(
+        traffic([0, 1], 2, 2, "param_levels", "alternate")))
     write_json(root / "BENCHMARK.json", manifest())
     return root
 

@@ -17,7 +17,10 @@ import pytest
 
 import benchtiny
 
-WORKLOADS = ["ens-0p1.wr", "hammer-1mib.wr"]
+WORKLOADS = ["ens-0p1.wr", "hammer-1mib.wr", "hammer-1mib.read", "ens-0p1.archive"]
+#: the cells whose requests reach a step after a cycle's first, which a
+#: stale answer needs: readers alone read the prefill's first step
+STALE_WORKLOADS = ["ens-0p1.wr", "hammer-1mib.wr"]
 
 
 @pytest.fixture(scope="module")
@@ -76,7 +79,7 @@ def test_fields_packed_at_another_width_are_not_correct(root, workload):
     assert "offgrid16" in _failed(compare(o))
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
+@pytest.mark.parametrize("workload", STALE_WORKLOADS)
 @pytest.mark.parametrize("relabel", [False, True], ids=["own-keys", "asked-keys"])
 def test_stale_answers_are_not_correct(root, workload, relabel):
     from fdbbench import faults
