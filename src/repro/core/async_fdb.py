@@ -299,6 +299,10 @@ class AsyncFDB(FDBClient):
     def _many_fetch(self, keys: list[Key]) -> list[DataHandle | None]:
         return self._fan_out(keys, self.fdb.retrieve_batch)
 
+    def _many_fetch_parts(self, keys: list[Key]):
+        # the fan-out, as one part
+        return iter([(list(range(len(keys))), self._many_fetch(keys), False)])
+
     # ------------------------------------------------------------- pass-through
     @property
     def store(self):

@@ -278,6 +278,22 @@ class FDBClient(abc.ABC):
         out)."""
         return self.retrieve_batch(keys)
 
+    def _many_fetch_parts(self, keys: list[Key]) -> Iterator[tuple[list[int], list, bool]]:
+        """The part source a FieldSet streams through (override with
+        :meth:`_many_fetch`)."""
+        return self.retrieve_batch_parts(keys)
+
+    def retrieve_batch_parts(
+        self, keys: Sequence[Key | Mapping[str, str]]
+    ) -> Iterator[tuple[list[int], list[DataHandle | None], bool]]:
+        """``retrieve_batch`` in parts, ``(positions, handles, more)`` each,
+        handed on as each lands, so that a large batch need never sit whole
+        in memory: a client over the wire streams a reply past its part
+        bound.  ``more`` says the reply goes on past the part (a frame of it
+        is still to be read).  Here, the whole batch, fetched now, as one
+        part."""
+        return iter([(list(range(len(keys))), self.retrieve_batch(keys), False)])
+
     _fieldset_batch: int | None = 64
 
     def retrieve_many(self, request) -> FieldSet:
@@ -299,7 +315,8 @@ class FDBClient(abc.ABC):
                 keys = [e.key for e in self._list(req)]
             if tr.enabled:
                 sp.set("n_keys", len(keys))
-            return FieldSet(keys, self._many_fetch, batch_size=self._fieldset_batch)
+            return FieldSet(keys, self._many_fetch, batch_size=self._fieldset_batch,
+                            parts=self._many_fetch_parts)
 
     def read_many(self, request) -> dict[Key, bytes | None]:
         """Deprecated: use ``retrieve_many(request).read_all()``."""

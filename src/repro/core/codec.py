@@ -15,7 +15,8 @@ path:
   ``grib_unpack`` launch per shape group);
 - :class:`DecodedFieldSet` is the lazy read-side view: a partial
   ``retrieve_many`` slice decodes chunk by chunk, each chunk in one kernel
-  launch, as it is consumed;
+  launch, as it is consumed; the whole set decodes part by part as the
+  parts of a streamed reply land, into one ``(F, H, W)`` array;
 - :class:`CodecFDB` is the declarative facade — ``{"type": "codec",
   "nbits": 16, "inner": {...}}`` in :func:`~repro.core.config.build_fdb` —
   that fixes the pack width per tier, so a hot DAOS tier can pack at 16
@@ -44,6 +45,7 @@ so ``stats_snapshot()`` reports the compression win.
 
 from __future__ import annotations
 
+import queue
 import struct
 import threading
 import time
@@ -55,7 +57,7 @@ from ..kernels.grib_pack import grib_pack, grib_unpack, payload_dtype
 from ..obs.tracer import NULL_TRACER
 from .client import FDBClient, WipeReport
 from .datahandle import DataHandle
-from .fieldset import FieldSet
+from .fieldset import FieldResolutionError, FieldSet
 from .keys import Key
 from .request import Request
 from .schema import Schema
@@ -157,8 +159,12 @@ def parse_header(payload: bytes, *, context: str = "") -> CodecHeader:
 
 def take_fields(fields, idxs: Sequence[int]):
     """Index a field batch — an ``(F, H, W)`` array or a sequence of 2-D
-    arrays — by positions (routing facades split batches per tier/lane)."""
+    arrays — by positions (routing facades split batches per tier/lane).
+    A run of consecutive positions of an array is a view of it, not a copy."""
     if isinstance(fields, np.ndarray):
+        lo = idxs[0] if len(idxs) else 0
+        if list(idxs) == list(range(lo, lo + len(idxs))):
+            return fields[lo:lo + len(idxs)]
         return fields[np.asarray(idxs, dtype=np.intp)]
     return [fields[i] for i in idxs]
 
@@ -184,6 +190,16 @@ def _as_field_list(fields) -> list[np.ndarray]:
     return out
 
 
+def _stacked(fields, flist: list[np.ndarray], idxs: list[int]) -> np.ndarray:
+    """The fields at ``idxs`` as one ``(f, H, W)`` float32 array: the
+    caller's own array when it is that already, else a stacked copy."""
+    if isinstance(fields, np.ndarray) and len(idxs) == len(flist):
+        arr = fields[None] if fields.ndim == 2 else fields
+        if arr.dtype == np.float32 and arr.flags.c_contiguous:
+            return arr
+    return np.stack([flist[i] for i in idxs])
+
+
 def encode_fields(fields, *, nbits: int = 16, stats=None, tracer=None) -> list[bytes]:
     """Bit-pack a batch of fields into wire payloads.
 
@@ -194,11 +210,12 @@ def encode_fields(fields, *, nbits: int = 16, stats=None, tracer=None) -> list[b
     ``archive_batch``.  Returns one payload per field, in input order.
     ``tracer`` records one ``codec.pack`` span per kernel launch with
     effective/wire bytes, and inside it ``codec.pack.stack`` (the batch
-    stacked), ``codec.pack.device`` (the kernel call until codes, ref and
-    scale are host arrays) and ``codec.pack.frame`` (the narrowing cast and
-    the framing).
+    stacked, unless it is one float32 array already), ``codec.pack.device``
+    (the kernel call until codes, narrowed to their wire container on the
+    device, ref and scale are host arrays) and ``codec.pack.frame`` (the
+    framing).
     """
-    dtype = payload_dtype(nbits)  # validates nbits before any device work
+    payload_dtype(nbits)  # validates nbits before any device work
     tr = tracer if tracer is not None else NULL_TRACER
     flist = _as_field_list(fields)
     if not flist:
@@ -212,19 +229,20 @@ def encode_fields(fields, *, nbits: int = 16, stats=None, tracer=None) -> list[b
         h, w = shape
         with tr.span("codec.pack") as sp:
             with tr.span("codec.pack.stack"):
-                batch = np.stack([flist[i] for i in idxs])  # (f, H, W) float32
+                batch = _stacked(fields, flist, idxs)  # (f, H, W) float32
             with tr.span("codec.pack.device"):
                 codes, ref, scale = grib_pack(batch, nbits=nbits)
-                codes = np.asarray(codes)  # int32, device to host
+                codes = np.asarray(codes)  # the wire's container, device to host
                 ref = np.asarray(ref, dtype=np.float64)
                 scale = np.asarray(scale, dtype=np.float64)
             with tr.span("codec.pack.frame"):
-                codes = codes.astype(dtype)
                 for j, i in enumerate(idxs):
                     header = struct.pack(
                         _HEADER_FMT, _MAGIC, _VERSION, nbits, 0, h, w, ref[j], scale[j]
                     )
-                    payloads[i] = header + codes[j].tobytes()
+                    # one copy of the codes (a device-to-host array need not be
+                    # C-contiguous field by field)
+                    payloads[i] = b"".join((header, np.ascontiguousarray(codes[j])))
             if tr.enabled:
                 sp.set("nbits", nbits)
                 sp.set("fields", len(idxs))
@@ -258,9 +276,10 @@ def decode_payloads(
     ONE ``grib_unpack`` kernel launch per distinct field shape.  ``labels``
     (e.g. the MARS keys) contextualise :class:`CodecError` messages.
     ``tracer`` records one ``codec.unpack`` span per kernel launch with
-    effective/wire bytes, and inside it ``codec.unpack.stack`` (codes
-    widened to int32 and stacked, with ref and scale) and
-    ``codec.unpack.device`` (the kernel call until the decoded host array).
+    effective/wire bytes, and inside it ``codec.unpack.stack`` (views of
+    each payload's codes, uncopied, with ref and scale; the device stacks
+    and widens them) and ``codec.unpack.device`` (the kernel call, copies
+    in included, until the decoded host array).
     """
     tr = tracer if tracer is not None else NULL_TRACER
     t0 = time.perf_counter()
@@ -278,13 +297,12 @@ def decode_payloads(
         with tr.span("codec.unpack") as sp:
             with tr.span("codec.unpack.stack"):
                 dtype = payload_dtype(nbits)
-                codes = np.stack(
-                    [
-                        np.frombuffer(payloads[i], dtype=dtype, offset=CODEC_HEADER_SIZE)
-                        .reshape(h, w)
-                        .astype(np.int32)
-                        for i in idxs
-                    ]
+                # each field's codes where they lie in its payload: the
+                # device stacks and widens them
+                codes = tuple(
+                    np.frombuffer(payloads[i], dtype=dtype, count=h * w, offset=CODEC_HEADER_SIZE)
+                    .reshape(h, w)
+                    for i in idxs
                 )
                 ref = np.asarray([headers[i].ref for i in idxs], dtype=np.float32)
                 scale = np.asarray([headers[i].scale for i in idxs], dtype=np.float32)
@@ -309,14 +327,86 @@ def decode_payloads(
     return out
 
 
+def _payload_bytes(handles: list) -> int:
+    return sum(h.size for h in handles if h is not None)
+
+
+class _PartFeed:
+    """Takes the rest of a FieldSet's parts on a thread of its own, once the
+    consumer holds the first, holding at most two parts (the one the
+    consumer decodes and the next), so that the next part lands while the
+    current one decodes.  ``buffered_max`` is the most payload bytes held in
+    parts at once: received, and not yet decoded, ``held`` (the first
+    part's) included."""
+
+    def __init__(self, parts: Iterator, held: int):
+        self._parts = parts
+        self._slots = threading.Semaphore(1)  # the first part holds the other
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._stop = False
+        self._mu = threading.Lock()
+        self._held = held
+        self.buffered_max = held
+        self._thread = threading.Thread(target=self._run, daemon=True, name="codec-part-feed")
+        self._thread.start()
+
+    def _run(self) -> None:
+        try:
+            while True:
+                self._slots.acquire()
+                part = None if self._stop else next(self._parts, None)
+                if part is None:
+                    break
+                nbytes = _payload_bytes(part[1])
+                with self._mu:
+                    self._held += nbytes
+                    self.buffered_max = max(self.buffered_max, self._held)
+                self._q.put((part, nbytes))
+            self._q.put(None)
+        except BaseException as e:  # raised again in the consumer, which would wait otherwise
+            self._q.put(e)
+            if not isinstance(e, Exception):
+                raise
+        finally:
+            close = getattr(self._parts, "close", None)
+            if close is not None:
+                close()
+
+    def get(self):
+        """The next ``(part, nbytes)``, or ``None`` after the last part."""
+        item = self._q.get()
+        if isinstance(item, BaseException):
+            raise item
+        return item
+
+    def done(self, nbytes: int) -> None:
+        """The consumer has decoded a part of ``nbytes``."""
+        with self._mu:
+            self._held -= nbytes
+        self._slots.release()
+
+    def close(self) -> None:
+        """Stop taking parts (the one in flight is still received)."""
+        self._stop = True
+        self._slots.release()
+
+
 class DecodedFieldSet:
     """The lazy result of :meth:`FDBClient.retrieve_fields`.
 
     Wraps a :class:`~repro.core.fieldset.FieldSet` and decodes on first
     touch, chunk by chunk — iterating a partial ``retrieve_many`` slice
     pays one backend fetch AND one ``grib_unpack`` launch per chunk, never
-    per field.  Decoded arrays are memoised; the underlying byte handles
-    are read and closed as each chunk resolves.
+    per field.  :meth:`read_all` and :meth:`arrays` take what is left of
+    the set part by part instead, as the parts of a streamed reply land
+    (the FieldSet's part source): one launch per part, written into one
+    preallocated ``(F, H, W)`` array.  The first part is taken on the
+    caller's thread; only when the reply goes on past it is the rest
+    received on a thread of the set's own, the next part landing while the
+    current one decodes.  Decoded arrays are memoised; the underlying byte
+    handles are read and closed as each chunk or part resolves.
+    ``buffered_max`` is the most payload bytes held in received parts at
+    once, where the parts went through that thread.
     """
 
     def __init__(
@@ -327,6 +417,8 @@ class DecodedFieldSet:
         self._stats = stats
         self._tracer = tracer
         self._arrays: list[np.ndarray | None | type(...)] = [...] * len(fieldset)
+        self._out: np.ndarray | None = None  # the (F, H, W) array parts decode into
+        self.buffered_max = 0
         self._mu = threading.Lock()
 
     # ------------------------------------------------------------- resolution
@@ -353,6 +445,77 @@ class DecodedFieldSet:
             )
             for j, a in zip(idxs, decoded):
                 self._arrays[j] = a
+
+    def _decode_parts(self) -> None:
+        """Decode every field not decoded yet, part by part as the parts land
+        (see class docstring).  ``codec.unpack.wait`` spans time each wait
+        for a part that has not landed yet."""
+        tr = self._tracer if self._tracer is not None else NULL_TRACER
+        with self._mu:
+            if all(a is not ... for a in self._arrays):
+                return
+            parts = self._fs.parts()
+            feed: _PartFeed | None = None
+            try:
+                while True:
+                    with tr.span("codec.unpack.wait"):
+                        part = next(parts, None) if feed is None else feed.get()
+                    if part is None:
+                        break
+                    if feed is None:
+                        nbytes = _payload_bytes(part[1])
+                        if part[2]:  # the reply goes on: take the rest meanwhile
+                            feed = _PartFeed(parts, nbytes)
+                    else:
+                        part, nbytes = part
+                    self._decode_part(*part[:2])
+                    if feed is not None:
+                        feed.done(nbytes)
+            finally:
+                if feed is None:
+                    close = getattr(parts, "close", None)
+                    if close is not None:
+                        close()
+                else:
+                    feed.close()
+                    self.buffered_max = feed.buffered_max
+                    if self._stats is not None:
+                        with self._stats.lock:
+                            c = self._stats.counters
+                            c["unpack_part_buffered_max"] = max(
+                                c["unpack_part_buffered_max"], feed.buffered_max)
+            left = sum(a is ... for a in self._arrays)
+            if left:
+                keys = [k for k, a in zip(self._fs.keys, self._arrays) if a is ...]
+                raise FieldResolutionError(len(self._arrays), len(self._arrays) - left, keys)
+
+    def _decode_part(self, pos: list[int], handles: list) -> None:
+        """Decode the positions of ``pos`` not decoded yet (a chunk decoded
+        before has read and closed its handles already)."""
+        todo = [(j, h) for j, h in zip(pos, handles) if self._arrays[j] is ...]
+        pos = [j for j, _ in todo]
+        payloads: list[bytes | None] = []
+        for _, h in todo:
+            if h is None:
+                payloads.append(None)
+            else:
+                try:
+                    payloads.append(h.read())
+                finally:
+                    h.close()
+        decoded = decode_payloads(
+            payloads,
+            stats=self._stats,
+            labels=[self._fs.keys[j] for j in pos],
+            tracer=self._tracer,
+        )
+        for j, a in zip(pos, decoded):
+            if a is not None and self._out is None:
+                self._out = np.empty((len(self._arrays), *a.shape), np.float32)
+            if a is not None and a.shape == self._out.shape[1:]:
+                self._out[j] = a
+                a = self._out[j]
+            self._arrays[j] = a
 
     # -------------------------------------------------------------- container
     @property
@@ -389,21 +552,23 @@ class DecodedFieldSet:
 
     # ------------------------------------------------------------ convenience
     def read_all(self) -> dict[Key, np.ndarray | None]:
-        """Decode everything: ONE whole-batch backend fetch (the fieldset's
-        amortised path), then one unpack launch per field shape."""
-        self._fs.handles()  # whole-set resolve in one vectored fetch
-        self._decode_range(0, len(self._fs))
+        """Decode everything not decoded yet: part by part as the parts land
+        when nothing was, else the rest in ONE whole-batch backend fetch and
+        one unpack launch per field shape.  Once all is decoded, nothing is
+        fetched again."""
+        self._decode_parts()
         return dict(zip(self._fs.keys, self._arrays))
 
     def missing(self) -> list[Key]:
         """Keys whose field is absent from the FDB."""
-        self._fs.handles()
-        self._decode_range(0, len(self._fs))
+        self.read_all()
         return [k for k, a in zip(self._fs.keys, self._arrays) if a is None]
 
     def arrays(self) -> np.ndarray:
-        """The whole set stacked as one ``(F, H, W)`` array — raises
-        :class:`CodecError` when fields are absent or shapes are ragged."""
+        """The whole set as one ``(F, H, W)`` array — the array the parts
+        were decoded into, not a copy, when :meth:`read_all` took them —
+        raises :class:`CodecError` when fields are absent or shapes are
+        ragged."""
         all_ = self.read_all()
         absent = [k for k, a in all_.items() if a is None]
         if absent:
@@ -412,6 +577,8 @@ class DecodedFieldSet:
         shapes = {a.shape for a in mats}
         if len(shapes) > 1:
             raise CodecError(f"cannot stack ragged field shapes {sorted(shapes)}")
+        if self._out is not None and all(a.base is self._out for a in mats):
+            return self._out
         return np.stack(mats)
 
 
@@ -451,6 +618,9 @@ class CodecFDB(FDBClient):
 
     def retrieve_batch(self, keys) -> list[DataHandle | None]:
         return self.inner.retrieve_batch(keys)
+
+    def retrieve_batch_parts(self, keys):
+        return self.inner.retrieve_batch_parts(keys)
 
     def retrieve_many(self, request) -> FieldSet:
         # the inner facade's fan-out/amortisation (AsyncFDB reader pool,

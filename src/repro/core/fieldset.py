@@ -53,7 +53,11 @@ class FieldSet:
     Resolution happens in chunks of ``batch_size`` on first touch and is
     memoised, so iterating twice costs one backend round per chunk.
     ``batch_size=None`` resolves everything in ONE fetch (used by AsyncFDB,
-    whose fetch fans the batch out over its reader pool).
+    whose fetch fans the batch out over its reader pool).  ``parts`` is the
+    owning client's part source (``retrieve_batch_parts``): called with the
+    keys, it returns an iterator of ``(positions, handles, more)`` parts
+    that together hold every key once, each handed on as it lands, which
+    :meth:`parts` passes on to a caller that takes the whole set once.
     """
 
     def __init__(
@@ -62,9 +66,11 @@ class FieldSet:
         fetch: Callable[[list[Key]], Sequence[DataHandle | None]],
         *,
         batch_size: int | None = 64,
+        parts: Callable[[list[Key]], Iterator[tuple[list[int], list, bool]]] | None = None,
     ):
         self._keys: tuple[Key, ...] = tuple(keys)
         self._fetch = fetch
+        self._parts = parts
         self._batch = len(self._keys) if batch_size is None else max(1, batch_size)
         self._handles: list[DataHandle | None | type(...)] = [...] * len(self._keys)
         self._index: dict[Key, int] = {}
@@ -147,6 +153,18 @@ class FieldSet:
     def __repr__(self) -> str:
         resolved = sum(1 for h in self._handles if h is not ...)
         return f"FieldSet({len(self._keys)} fields, {resolved} resolved)"
+
+    def parts(self) -> Iterator[tuple[list[int], list[DataHandle | None], bool]]:
+        """Every key's handle in parts, ``(positions, handles, more)`` each,
+        for a caller that takes the whole set once: through the owner's
+        part source while nothing is resolved (the parts are handed on as
+        they land and not kept here; ``more`` says the reply goes on past
+        the part), else the whole set, resolved now, as one part."""
+        with self._mu:
+            fresh = all(h is ... for h in self._handles)
+        if self._parts is not None and fresh:
+            return self._parts(list(self._keys))
+        return iter([(list(range(len(self._keys))), self.handles(), False)])
 
     # ------------------------------------------------------------ convenience
     def handles(self) -> list[DataHandle | None]:

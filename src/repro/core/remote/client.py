@@ -18,7 +18,14 @@ Transport behaviour, all bounded and configurable:
   fresh socket up to ``retries`` times with exponential backoff.  Safe for
   archives because FDB re-archive has replacement semantics.  Application
   errors the server reports (:class:`RemoteError`) are never retried — the
-  op ran and failed, a resend would just fail again.
+  op ran and failed, a resend would just fail again — and neither is a
+  frame over ``max_frame`` (:class:`~repro.core.remote.protocol.FrameTooLarge`),
+  which a resend would only build again;
+- a read's reply past the part bound arrives in parts
+  (:data:`~repro.core.remote.protocol.STREAM_EXT_VERSION`), handed on as
+  each lands (:meth:`RemoteFDB.retrieve_batch_parts`).  A fault before the
+  first part is retried as above; after it, the request raises, and never
+  returns part of an answer.
 
 The handshake carries the server's schema (name-resolved when registered,
 inline spec otherwise), so the client validates keys and expands requests
@@ -37,7 +44,9 @@ import queue
 import socket
 import threading
 import time
-from typing import Iterator, Mapping, Sequence
+from typing import Callable, Iterator, Mapping, Sequence
+
+import numpy as np
 
 from ...metrics.iostats import IOStats
 from ...obs.tracer import NULL_TRACER
@@ -48,12 +57,15 @@ from ..fieldset import FieldSet
 from ..keys import Key
 from ..request import Request
 from . import protocol as P
-from .protocol import Cursor, Op, ProtocolError, RemoteError, RemoteTimeout
+from .protocol import Cursor, FrameTooLarge, Op, ProtocolError, RemoteError, RemoteTimeout
 
 __all__ = ["RemoteFDB"]
 
 #: transport faults eligible for retry (application errors never are)
 _TRANSPORT_FAULTS = (OSError, ProtocolError, EOFError)
+
+#: buffers handed to one ``sendmsg`` call (Linux's IOV_MAX is 1024)
+_IOV_MAX = 512
 
 
 def _parse_addr(addr) -> tuple[str, int]:
@@ -64,6 +76,16 @@ def _parse_addr(addr) -> tuple[str, int]:
         if sep and port.isdigit():
             return host, int(port)
     raise ValueError(f"remote addr must be 'host:port' or (host, port), got {addr!r}")
+
+
+def _pieces(payload) -> list:
+    """A request payload as a list of buffers: one ``bytes``, or the list."""
+    return [payload] if isinstance(payload, (bytes, bytearray, memoryview)) else payload
+
+
+def _span(tracer, name: str, parent):
+    """A span under ``parent``, or under the thread's innermost open span."""
+    return tracer.span(name) if parent is None else tracer.span(name, parent=parent)
 
 
 class _Conn:
@@ -95,21 +117,48 @@ class _Conn:
             raise
 
     def call(
-        self, req_id: int, opcode: int, payload: bytes, tracer=NULL_TRACER
+        self, req_id: int, opcode: int, payload: bytes | list, tracer=NULL_TRACER, parent=None
     ) -> tuple[int, Cursor, int]:
-        """Send one frame, block for its response.  Returns
+        """Send one frame, its payload ``bytes`` or a list of buffers (sent
+        as they are, never joined), and block for its response.  Returns
         ``(response opcode, payload cursor, response bytes)``.  ``tracer``
-        records ``wire.send`` (framing and ``sendall``) and ``wire.recv``
-        (from the reply's 4-byte header to the split body); the time between
-        them is the wait for the server."""
-        with tracer.span("wire.send"):
-            self.sock.sendall(P.encode_frame(req_id, opcode, payload))
+        records ``wire.send`` (framing and sending) and ``wire.recv`` (from
+        the reply's 4-byte header to the split body) under ``parent`` (else
+        the thread's innermost open span); the time between them is the
+        wait for the server."""
+        pieces = _pieces(payload)
+        with _span(tracer, "wire.send", parent):
+            self._send([P.frame_head(req_id, opcode, P.pieces_len(pieces)), *pieces])
+        return self.recv(req_id, tracer, parent)
+
+    def _send(self, pieces: list) -> None:
+        """``sendall`` of the buffers in order, through vectored ``sendmsg``
+        calls: the kernel copies each buffer from where it lies."""
+        views = [v for v in (memoryview(p).cast("B") for p in pieces) if len(v)]
+        while views:
+            sent = self.sock.sendmsg(views[:_IOV_MAX])
+            while sent:
+                if sent >= len(views[0]):
+                    sent -= len(views.pop(0))
+                else:
+                    views[0] = views[0][sent:]
+                    sent = 0
+
+    def recv(self, req_id: int, tracer=NULL_TRACER, parent=None) -> tuple[int, Cursor, int]:
+        """The next frame of the reply to ``req_id``; a part of a streamed
+        reply is timed as ``wire.recv_part``, with its ``bytes`` and
+        ``fields``."""
         hdr = self._recv_exact(4, "frame header")
-        with tracer.span("wire.recv"):
-            body = self._recv_exact(
-                P.frame_length(hdr, max_frame=self._max_frame), "frame"
+        with _span(tracer, "wire.recv", parent) as sp:
+            body = self._recv_body(
+                P.frame_length(hdr, max_frame=self._max_frame, too_large=FrameTooLarge)
             )
             resp_id, resp_op, cur = P.split_frame(body)
+            if resp_op == Op.PART and tracer.enabled:
+                sp.name = "wire.recv_part"
+                sp.set("bytes", len(body))
+                # every reply encoding starts with its item count
+                sp.set("fields", int.from_bytes(body[5:9], "big"))
         if resp_id != req_id:
             raise ProtocolError(
                 f"response id {resp_id} does not match request id {req_id}"
@@ -126,11 +175,41 @@ class _Conn:
             n -= len(chunk)
         return b"".join(chunks)
 
+    def _recv_body(self, n: int) -> memoryview:
+        """A frame body of ``n`` bytes, received straight into one buffer
+        that nothing wrote before: the kernel's copy is its only pass."""
+        body = memoryview(np.empty(n, np.uint8))
+        got = 0
+        while got < n:
+            k = self.sock.recv_into(body[got:], n - got)
+            if not k:
+                raise ProtocolError(f"server closed the connection mid frame ({got}/{n} bytes)")
+            got += k
+        return body
+
     def close(self) -> None:
         try:
             self.sock.close()
         except OSError:
             pass
+
+
+def _positioned(reply: Iterator[tuple[list, bool]], n: int):
+    """``(positions, handles, more)`` of each part of a ``RETRIEVE_BATCH``
+    reply, which has to hold exactly one handle per key of ``n``."""
+    lo = 0
+    try:
+        for payloads, more in reply:
+            hi = lo + len(payloads)
+            if hi > n:
+                raise ProtocolError(f"server returned more than {n} handles for {n} keys")
+            yield list(range(lo, hi)), [None if p is None else MemoryDataHandle(p)
+                                        for p in payloads], more
+            lo = hi
+    finally:
+        reply.close()
+    if lo != n:
+        raise ProtocolError(f"server returned {lo} handles for {n} keys")
 
 
 class RemoteFDB(FDBClient):
@@ -221,22 +300,44 @@ class RemoteFDB(FDBClient):
                 self.wire_stats.record("remote_retry")
                 time.sleep(self._backoff * (2 ** (attempt - 1)))
 
-    def _call(self, opcode: int, payload: bytes, op_name: str) -> Cursor:
+    def _call(self, opcode: int, payload: bytes | list, op_name: str) -> Cursor:
         """One request/response round with pooling, timeout mapping and
-        bounded retry on transport faults.
+        bounded retry on transport faults (:meth:`_call_parts`, for an op
+        whose reply is one frame)."""
+        reply = self._call_parts(opcode, payload, op_name, lambda cur: cur,
+                                 self._trace.current())
+        try:
+            return next(reply)[0]
+        finally:
+            reply.close()
+
+    def _call_parts(self, opcode: int, payload: bytes | list, op_name: str,
+                    decode: Callable[[Cursor], object], parent) -> Iterator:
+        """One request (its payload ``bytes``, or a list of buffers sent as
+        they are), and its reply decoded frame by frame as each lands,
+        as ``(decoded, more)``: one frame (decoded once the wire span has
+        ended, ``more`` false), or the parts of a streamed reply (``more``
+        true: its ``END`` frame, which checks the run by count, is still to
+        be read).  Pooling, timeout mapping and bounded retry on transport
+        faults up to the first reply frame; a fault after the first part
+        raises, and so does an ``ERR`` among the parts.
 
         The whole round runs under a wire span, with ``wire.send`` and
-        ``wire.recv`` children per attempt.  When tracing is on AND the
-        connection negotiated the trace extension, the frame goes out
-        TRACE_FLAG'd with this span's context prefixed, so the server's op
-        span becomes a child of the wire span — the send/receive time and
-        the server-side time stitch into one trace."""
+        ``wire.recv`` (``wire.recv_part``) children per attempt.  The span
+        is started under ``parent`` (the caller's span when the generator
+        was made) and never entered, since it stays open across the yields.
+        When tracing is on AND the connection negotiated the trace
+        extension, the frame goes out TRACE_FLAG'd with this span's context
+        prefixed, so the server's op span becomes a child of the wire span —
+        the send/receive time and the server-side time stitch into one
+        trace."""
         if self._closed:
             raise RuntimeError("RemoteFDB is closed")
         tr = self._trace
-        with tr.span("wire.call") as sp:
-            if tr.enabled:
-                sp.name = "wire." + op_name
+        sp = tr.span("wire." + op_name if tr.enabled else "wire.call", parent=parent)
+        pieces = _pieces(payload)
+        nbytes_w = P.pieces_len(pieces)
+        try:
             attempt = 0
             while True:
                 conn = self._pool.get()
@@ -246,22 +347,20 @@ class RemoteFDB(FDBClient):
                     except BaseException:
                         self._pool.put(None)  # give the token back
                         raise
-                wire_op, wire_payload = opcode, payload
+                wire_op, wire_pieces = opcode, pieces
                 if tr.enabled and conn.ext_version >= P.TRACE_EXT_VERSION:
                     ctx = sp.context
                     wire_op = opcode | P.TRACE_FLAG
-                    wire_payload = (
-                        P.encode_trace_ctx(ctx.trace_id, ctx.span_id) + payload
-                    )
+                    wire_pieces = [P.encode_trace_ctx(ctx.trace_id, ctx.span_id), *pieces]
                 req_id = self._next_req_id()
                 t0 = time.perf_counter()
                 try:
-                    resp_op, cur, nread = conn.call(req_id, wire_op, wire_payload, tr)
+                    resp_op, cur, nread = conn.call(req_id, wire_op, wire_pieces, tr, sp)
                 except _TRANSPORT_FAULTS as e:
                     conn.close()
                     self._pool.put(None)
                     attempt += 1
-                    if attempt > self._retries:
+                    if isinstance(e, FrameTooLarge) or attempt > self._retries:
                         if isinstance(e, (socket.timeout, TimeoutError)):
                             raise RemoteTimeout(
                                 f"{op_name} timed out after {attempt} attempts "
@@ -271,26 +370,77 @@ class RemoteFDB(FDBClient):
                     self.wire_stats.record("remote_retry")
                     time.sleep(self._backoff * (2 ** (attempt - 1)))
                     continue
+                break
+            parts = 0
+            if resp_op == Op.PART:
+                parts, nread, cur = yield from self._parts(conn, req_id, cur, nread, decode, sp)
+            else:
                 self._pool.put(conn)
-                self.wire_stats.record(
-                    op_name,
-                    seconds=time.perf_counter() - t0,
-                    nbytes_w=len(payload),
-                    nbytes_r=nread,
-                    shard=f"conn{conn.conn_id}",
+            self.wire_stats.record(
+                op_name,
+                seconds=time.perf_counter() - t0,
+                nbytes_w=nbytes_w,
+                nbytes_r=nread,
+                shard=f"conn{conn.conn_id}",
+            )
+            if tr.enabled:
+                sp.set("bytes_out", P.pieces_len(wire_pieces))
+                sp.set("bytes_in", nread)
+                sp.set("attempts", attempt + 1)
+                sp.set("conn", conn.conn_id)
+                if parts:
+                    sp.set("parts", parts)
+            if parts:
+                return
+            if resp_op == Op.ERR:
+                raise P.decode_error(cur)
+            if resp_op != Op.OK:
+                raise ProtocolError(
+                    f"unexpected response opcode {resp_op:#x} to {op_name}"
                 )
-                if tr.enabled:
-                    sp.set("bytes_out", len(wire_payload))
-                    sp.set("bytes_in", nread)
-                    sp.set("attempts", attempt + 1)
-                    sp.set("conn", conn.conn_id)
-                if resp_op == Op.ERR:
-                    raise P.decode_error(cur)
-                if resp_op != Op.OK:
-                    raise ProtocolError(
-                        f"unexpected response opcode {resp_op:#x} to {op_name}"
-                    )
-                return cur
+        except BaseException as e:
+            if not isinstance(e, GeneratorExit):
+                sp.set("error", type(e).__name__)
+            raise
+        finally:
+            sp.end()
+        # a single frame's payload decodes after the round, as it always has
+        yield decode(cur), False
+
+    def _parts(self, conn: _Conn, req_id: int, cur: Cursor, nread: int, decode, sp):
+        """Yield each part of a streamed reply, decoded, up to its ``END``
+        frame; returns ``(parts, bytes read, END cursor)``.  The connection
+        goes back to the pool once the run has ended (``END`` or ``ERR``),
+        and is closed if the run is cut short or the caller stops early."""
+        parts = items = 0
+        resp_op = Op.PART
+        ended = False
+        try:
+            while resp_op == Op.PART:
+                got = decode(cur)
+                parts += 1
+                items += len(got)
+                yield got, True
+                resp_op, cur, n = conn.recv(req_id, self._trace, sp)
+                nread += n
+            ended = resp_op in (Op.END, Op.ERR)
+        finally:
+            if ended:
+                self._pool.put(conn)
+            else:
+                conn.close()
+                self._pool.put(None)
+        if resp_op == Op.ERR:
+            raise P.decode_error(cur)
+        if resp_op != Op.END:
+            raise ProtocolError(f"unexpected opcode {resp_op:#x} in a streamed reply")
+        want = P.decode_end(cur)
+        if want != (parts, items):
+            raise ProtocolError(
+                f"streamed reply ended after {parts} parts and {items} items, "
+                f"its END frame counts {want[0]} and {want[1]}"
+            )
+        return parts, nread, cur
 
     # ----------------------------------------------------------- required hooks
     def archive(self, key: Key | Mapping[str, str], data: bytes) -> None:
@@ -305,27 +455,39 @@ class RemoteFDB(FDBClient):
         for key, data in items:
             k = self._as_key(key)
             self.schema.validate(k)  # fail fast, before paying the round
-            wire_items.append((k, bytes(data)))
+            wire_items.append((k, data))
+        # the payloads go out as they lie, never joined into one frame
         cur = self._call(
-            Op.ARCHIVE_BATCH, P.encode_archive_batch(wire_items), "archive_batch"
+            Op.ARCHIVE_BATCH, P.archive_batch_pieces(wire_items), "archive_batch"
         )
         cur.expect_end()
 
     def retrieve_batch(
         self, keys: Sequence[Key | Mapping[str, str]]
     ) -> list[DataHandle | None]:
+        out: list[DataHandle | None] = []
+        for _, handles, _ in self._batch_parts(keys, views=False):
+            out.extend(handles)
+        return out
+
+    def retrieve_batch_parts(self, keys: Sequence[Key | Mapping[str, str]]):
+        """``retrieve_batch`` in parts, ``(positions, handles, more)`` each:
+        one part, or one per part of a streamed reply, handed on as it lands.
+        Nothing is sent until the first part is asked for; the wire span
+        belongs to the caller's span at this call.  A part's handles read as
+        views into the frame it came in, uncopied, which they keep alive."""
+        return self._batch_parts(keys, views=True)
+
+    def _batch_parts(self, keys, *, views: bool):
         ks = [self._as_key(k) for k in keys]
         for k in ks:
             self.schema.validate(k)
         if not ks:
-            return []
-        cur = self._call(Op.RETRIEVE_BATCH, P.encode_keys(ks), "retrieve_batch")
-        payloads = P.decode_handles(cur)
-        if len(payloads) != len(ks):
-            raise ProtocolError(
-                f"server returned {len(payloads)} handles for {len(ks)} keys"
-            )
-        return [None if p is None else MemoryDataHandle(p) for p in payloads]
+            return iter(())
+        reply = self._call_parts(Op.RETRIEVE_BATCH, P.encode_keys(ks), "retrieve_batch",
+                                 lambda cur: P.decode_handles(cur, views=views),
+                                 self._trace.current())
+        return _positioned(reply, len(ks))
 
     def flush(self) -> None:
         self._call(Op.FLUSH, b"", "flush").expect_end()
@@ -336,12 +498,14 @@ class RemoteFDB(FDBClient):
 
     def retrieve_many(self, request) -> FieldSet:
         """One wire round for the WHOLE request: the server resolves and
-        reads every matched field and ships payloads back in a single
-        fieldset frame (the catalogue listing never crosses the wire just to
-        come back as per-key fetches)."""
+        reads every matched field and ships payloads back in one fieldset
+        frame, or in parts past the part bound (the catalogue listing never
+        crosses the wire just to come back as per-key fetches)."""
         req = self._validated_request(request)
-        cur = self._call(Op.RETRIEVE_MANY, P.encode_request(req), "retrieve_many")
-        items = P.decode_fieldset(cur)
+        items: list[tuple[Key, bytes | None]] = []
+        for part, _ in self._call_parts(Op.RETRIEVE_MANY, P.encode_request(req), "retrieve_many",
+                                     P.decode_fieldset, self._trace.current()):
+            items.extend(part)
         keys = [k for k, _ in items]
         table: dict[Key, bytes | None] = {}
         for k, p in items:

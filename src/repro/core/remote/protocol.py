@@ -20,6 +20,10 @@ domain types ride on their existing canonical text forms:
 
 A frame longer than ``max_frame`` is a protocol error, not an allocation:
 mis-framed or hostile input fails fast instead of exhausting memory.
+A read's reply larger than :func:`part_bound` goes, to a peer that negotiated
+:data:`STREAM_EXT_VERSION`, as a run of ``PART`` frames under the request's
+id, each holding whole items in the op's own reply encoding, closed by an
+``END`` frame that counts the parts and items; any other reply is one frame.
 Errors travel as ``ERR`` frames carrying the server-side exception type name
 and message; the client raises :class:`RemoteError` (transport faults raise
 the underlying ``OSError``/:class:`RemoteTimeout` instead, which is what the
@@ -40,6 +44,7 @@ __all__ = [
     "MAGIC",
     "PROTOCOL_VERSION",
     "DEFAULT_MAX_FRAME",
+    "FrameTooLarge",
     "ProtocolError",
     "RemoteError",
     "RemoteTimeout",
@@ -61,6 +66,12 @@ PROTOCOL_VERSION = 1
 #: prefix) and the ``Op.TRACE`` round are understood.
 TRACE_EXT_VERSION = 2
 
+#: extension level >= 3 also means: a read's reply past :func:`part_bound`
+#: may come as ``PART`` frames closed by an ``END`` frame (module docstring).
+#: Both ends echo the lower of the two levels, so a level-2 peer gets every
+#: reply as one frame, as before.
+STREAM_EXT_VERSION = 3
+
 #: opcode bit marking a request frame whose payload is prefixed with a
 #: trace context (u64 trace id + u64 parent span id).  Request opcodes stay
 #: below 0x40 and responses use the 0x80 bit, so the flag is unambiguous.
@@ -69,6 +80,11 @@ TRACE_FLAG = 0x40
 #: refuse frames beyond this many body bytes (1 GiB) — far above any real
 #: batch, far below "the peer sent garbage length bytes"
 DEFAULT_MAX_FRAME = 1 << 30
+
+#: frame body bytes above which a read's reply is streamed in parts: above
+#: the largest reply of the benchmark's 8-level reads (207.5 MB), so those
+#: stay single frames, and a quarter of :data:`DEFAULT_MAX_FRAME`
+PART_BYTES = 256 << 20
 
 _U8 = struct.Struct("!B")
 _U16 = struct.Struct("!H")
@@ -79,6 +95,11 @@ _HDR = struct.Struct("!IB")  # request_id, opcode
 
 class ProtocolError(RuntimeError):
     """Mis-framed, truncated, or version-incompatible wire data."""
+
+
+class FrameTooLarge(ProtocolError):
+    """A frame longer than the reader's ``max_frame``: sending the same
+    request again would bring the same frame, so it is never retried."""
 
 
 class RemoteError(RuntimeError):
@@ -112,12 +133,14 @@ class Op:
     TRACE = 0x09
     OK = 0x80
     ERR = 0x81
+    PART = 0x82
+    END = 0x83
 
     NAMES = {
         HELLO: "hello", ARCHIVE_BATCH: "archive_batch",
         RETRIEVE_BATCH: "retrieve_batch", RETRIEVE_MANY: "retrieve_many",
         LIST: "list", WIPE: "wipe", FLUSH: "flush", STATS: "stats",
-        TRACE: "trace", OK: "ok", ERR: "err",
+        TRACE: "trace", OK: "ok", ERR: "err", PART: "part", END: "end",
     }
 
 
@@ -143,7 +166,8 @@ class Cursor:
 
     It reads through a ``memoryview`` from ``pos`` on, so a frame's header
     is skipped without copying its body; every byte string it hands out is
-    a ``bytes`` copy, so no caller holds a view into a wire buffer."""
+    a ``bytes`` copy, so no caller holds a view into a wire buffer, but for
+    :meth:`view_`'s."""
 
     __slots__ = ("_buf", "_pos")
 
@@ -184,6 +208,12 @@ class Cursor:
     def bytes_(self, what: str = "bytes") -> bytes:
         return self._take(self.u32(f"{what} length"), what)
 
+    def view_(self, what: str = "bytes") -> memoryview:
+        """A length-prefixed byte string as a read-only view into the frame
+        (no copy; the view keeps the frame's buffer alive)."""
+        start = self._skip(self.u32(f"{what} length"), what)
+        return self._buf[start:self._pos].toreadonly()
+
     def str_(self, what: str = "str") -> str:
         start = self._skip(self.u32(f"{what} length"), what)
         return str(self._buf[start:self._pos], "utf-8")
@@ -206,17 +236,37 @@ def encode_frame(req_id: int, opcode: int, payload: bytes = b"") -> bytes:
     return b"".join((_U32.pack(_HDR.size + len(payload)), _HDR.pack(req_id, opcode), payload))
 
 
-def frame_length(header: bytes, *, max_frame: int = DEFAULT_MAX_FRAME) -> int:
-    """Decode the 4-byte length prefix, enforcing the frame-size bound."""
+def frame_head(req_id: int, opcode: int, body_bytes: int) -> bytes:
+    """The length prefix and header of a frame whose payload is
+    ``body_bytes`` long: what :func:`encode_frame` puts before the payload,
+    for a frame sent as pieces (:func:`pieces_len`) without joining them."""
+    return _U32.pack(_HDR.size + body_bytes) + _HDR.pack(req_id, opcode)
+
+
+def pieces_len(pieces) -> int:
+    """Bytes of a payload held as a sequence of buffers."""
+    return sum(memoryview(p).nbytes for p in pieces)
+
+
+def frame_length(header: bytes, *, max_frame: int = DEFAULT_MAX_FRAME,
+                 too_large: type[ProtocolError] = ProtocolError) -> int:
+    """Decode the 4-byte length prefix, enforcing the frame-size bound
+    (``too_large`` is the error raised past it)."""
     (n,) = _U32.unpack(header)
     if n < _HDR.size:
         raise ProtocolError(f"frame body of {n} bytes is shorter than the header")
     if n > max_frame:
-        raise ProtocolError(
+        raise too_large(
             f"frame of {n} bytes exceeds the {max_frame}-byte limit "
             "(mis-framed stream or oversized batch)"
         )
     return n
+
+
+def part_bound(max_frame: int = DEFAULT_MAX_FRAME) -> int:
+    """Frame body bytes above which a reply is streamed: :data:`PART_BYTES`,
+    capped at the reader's ``max_frame``."""
+    return min(PART_BYTES, max_frame)
 
 
 def split_frame(body) -> tuple[int, int, Cursor]:
@@ -233,7 +283,7 @@ def split_frame(body) -> tuple[int, int, Cursor]:
 # op payloads — encode/decode pairs shared by both ends of the wire
 # ---------------------------------------------------------------------------
 
-def encode_hello(ext_version: int = TRACE_EXT_VERSION) -> bytes:
+def encode_hello(ext_version: int = STREAM_EXT_VERSION) -> bytes:
     """HELLO payload: base magic+version, plus the extension level as an
     OPTIONAL trailing u16 a v1 server never reads."""
     out = MAGIC + _U16.pack(PROTOCOL_VERSION)
@@ -278,12 +328,19 @@ def decode_trace_ctx(cur: Cursor) -> tuple[int, int]:
     return cur.u64("trace id"), cur.u64("parent span id")
 
 
-def encode_archive_batch(items: Sequence[tuple[Key, bytes]]) -> bytes:
+def archive_batch_pieces(items: Sequence[tuple[Key, bytes]]) -> list:
+    """:func:`encode_archive_batch` as a list of buffers, the payloads
+    themselves among them, uncopied."""
     parts = [_U32.pack(len(items))]
     for key, data in items:
         parts.append(pack_str(key.canonical()))
-        parts.append(pack_bytes(data))
-    return b"".join(parts)
+        parts.append(_U32.pack(memoryview(data).nbytes))
+        parts.append(data)
+    return parts
+
+
+def encode_archive_batch(items: Sequence[tuple[Key, bytes]]) -> bytes:
+    return b"".join(archive_batch_pieces(items))
 
 
 def decode_archive_batch(cur: Cursor) -> list[tuple[Key, bytes]]:
@@ -310,25 +367,33 @@ def decode_request(cur: Cursor) -> Request:
     return Request.parse(cur.str_("request"))
 
 
-def encode_handles(payloads: Sequence[bytes | None]) -> bytes:
+def _handle_pieces(payloads: Sequence[bytes | None]) -> list[bytes]:
     parts = [_U32.pack(len(payloads))]
     for p in payloads:
         if p is None:
             parts.append(_U8.pack(0))
         else:
             parts.append(_U8.pack(1))
-            parts.append(pack_bytes(p))
-    return b"".join(parts)
+            parts.append(_U32.pack(len(p)))
+            parts.append(p)
+    return parts
 
 
-def decode_handles(cur: Cursor) -> list[bytes | None]:
-    out: list[bytes | None] = []
+def encode_handles(payloads: Sequence[bytes | None]) -> bytes:
+    return b"".join(_handle_pieces(payloads))
+
+
+def decode_handles(cur: Cursor, *, views: bool = False) -> list[bytes | memoryview | None]:
+    """The payloads of a ``RETRIEVE_BATCH`` reply: ``bytes`` copies, or
+    with ``views`` read-only views into the frame (:meth:`Cursor.view_`)."""
+    take = cur.view_ if views else cur.bytes_
+    out: list[bytes | memoryview | None] = []
     for _ in range(cur.u32("handle count")):
-        out.append(cur.bytes_("field payload") if cur.u8("present flag") else None)
+        out.append(take("field payload") if cur.u8("present flag") else None)
     return out
 
 
-def encode_fieldset(items: Sequence[tuple[Key, bytes | None]]) -> bytes:
+def _fieldset_pieces(items: Sequence[tuple[Key, bytes | None]]) -> list[bytes]:
     parts = [_U32.pack(len(items))]
     for key, p in items:
         parts.append(pack_str(key.canonical()))
@@ -336,8 +401,13 @@ def encode_fieldset(items: Sequence[tuple[Key, bytes | None]]) -> bytes:
             parts.append(_U8.pack(0))
         else:
             parts.append(_U8.pack(1))
-            parts.append(pack_bytes(p))
-    return b"".join(parts)
+            parts.append(_U32.pack(len(p)))
+            parts.append(p)
+    return parts
+
+
+def encode_fieldset(items: Sequence[tuple[Key, bytes | None]]) -> bytes:
+    return b"".join(_fieldset_pieces(items))
 
 
 def decode_fieldset(cur: Cursor) -> list[tuple[Key, bytes | None]]:
@@ -346,6 +416,57 @@ def decode_fieldset(cur: Cursor) -> list[tuple[Key, bytes | None]]:
         key = Key.from_canonical(cur.str_("key"))
         out.append((key, cur.bytes_("field payload") if cur.u8("present flag") else None))
     return out
+
+
+# ---------------------------------------------------------------------------
+# read replies — one frame, or parts closed by END (module docstring)
+# ---------------------------------------------------------------------------
+
+def reply_item_bytes(opcode: int, key: Key, size: int | None) -> int:
+    """Encoded bytes of one item of a read's reply: the field's payload of
+    ``size`` bytes (``None``: absent), and its key for ``RETRIEVE_MANY``."""
+    n = _U8.size + (0 if size is None else _U32.size + size)
+    if opcode == Op.RETRIEVE_MANY:
+        n += _U32.size + len(key.canonical().encode("utf-8"))
+    return n
+
+
+def split_reply(opcode: int, items: Sequence[tuple[Key, int | None]], bound: int) -> list[range]:
+    """Index ranges of the frames a read's reply of ``items`` (key, payload
+    size or ``None``) takes: one range when its frame body fits ``bound``,
+    else parts of whole items, each within ``bound`` unless one item alone
+    is larger."""
+    head = _HDR.size + _U32.size
+    sizes = [reply_item_bytes(opcode, k, n) for k, n in items]
+    if head + sum(sizes) <= bound:
+        return [range(len(items))]
+    parts, lo, body = [], 0, head
+    for i, n in enumerate(sizes):
+        if i > lo and body + n > bound:
+            parts.append(range(lo, i))
+            lo, body = i, head
+        body += n
+    parts.append(range(lo, len(items)))
+    return parts
+
+
+def reply_pieces(opcode: int, items: Sequence[tuple[Key, bytes | None]]) -> list:
+    """The payload of a read's reply holding ``items`` as a list of
+    buffers, the field payloads among them uncopied: the pieces of
+    :func:`encode_handles` of the payloads for ``RETRIEVE_BATCH``, of
+    :func:`encode_fieldset` of the items for ``RETRIEVE_MANY``."""
+    if opcode == Op.RETRIEVE_MANY:
+        return _fieldset_pieces(items)
+    return _handle_pieces([p for _, p in items])
+
+
+def encode_end(parts: int, items: int) -> bytes:
+    return _U32.pack(parts) + _U32.pack(items)
+
+
+def decode_end(cur: Cursor) -> tuple[int, int]:
+    """``(parts, items)`` an ``END`` frame says the run held."""
+    return cur.u32("part count"), cur.u32("item count")
 
 
 def encode_listing(entries) -> bytes:

@@ -36,6 +36,12 @@ Concurrency model:
   contention lands on the backend's own locks, exactly where the paper
   puts it.  It is the connection's only writer: replies go out through
   ``transport.write`` and wait for the transport's write buffer to drain;
+- a read's reply past the part bound goes, to a peer that negotiated
+  :data:`~repro.core.remote.protocol.STREAM_EXT_VERSION`, as ``PART``
+  frames of whole items closed by ``END``: the executor thread reads one
+  part's payloads from the store, frames it, hands it to the loop and waits
+  until the transport's write buffer has drained before it reads the next,
+  so a served read holds about one part, not the whole reply;
 - wire-level request batching: consecutive queued ``ARCHIVE_BATCH`` frames
   are coalesced into ONE backend ``archive_batch`` call (each frame still
   gets its own response), so a bursty client amortises backend rounds the
@@ -48,7 +54,12 @@ counts, per-connection op shards) accumulates in ``wire_stats`` — an
 from each header to its complete body, and ``counters["wire_frame_read_calls"]``
 the ``recv_into`` calls that filled those bodies; a traced op's ``server.*``
 span carries the same for its frame as ``read_s`` and ``read_calls``, beside
-``queued_s``.
+``queued_s``.  A streamed reply records each part as ``wire_part`` (its
+frame bytes, and the seconds from its first store read to its write
+drained) and the largest part held in ``counters["wire_part_buffered_max"]``;
+its traced ``server.<op>`` span holds one ``server.<op>.part`` span a part,
+with the part's ``bytes`` and ``fields``, and carries ``parts`` and
+``buffered_max``.
 """
 
 from __future__ import annotations
@@ -91,6 +102,9 @@ _SERVER_SPANS = {
     Op.STATS: "server.stats",
 }
 
+#: span names of the parts of a streamed reply
+_PART_SPANS = {op: _SERVER_SPANS[op] + ".part" for op in (Op.RETRIEVE_BATCH, Op.RETRIEVE_MANY)}
+
 
 class _Frame(NamedTuple):
     """One frame body off the wire and how it was read."""
@@ -125,6 +139,8 @@ class _Connection(asyncio.BufferedProtocol):
         self._lost = False
         self._drained: asyncio.Future | None = None  # set while writing is paused
         self.transport: asyncio.Transport | None = None
+        #: the HELLO extension level both ends speak (1 until the handshake)
+        self.ext = 1
 
     # ------------------------------------------------------------- intake
     def connection_made(self, transport) -> None:
@@ -247,13 +263,25 @@ class _Connection(asyncio.BufferedProtocol):
             self._drained.set_result(None)
         self._drained = None
 
-    async def send(self, req_id: int, opcode: int, payload: bytes) -> None:
-        """Write one reply frame, then wait while the transport's write
-        buffer is above its high-water mark.  One coroutine of the
+    async def send(self, req_id: int, opcode: int, payload: bytes | list) -> None:
+        """Send a reply whose payload is ``bytes``, or a list of buffers
+        (a read's reply, its field payloads among them) written as they are."""
+        if isinstance(payload, list):
+            await self.send_frame([P.frame_head(req_id, opcode, P.pieces_len(payload)), *payload])
+        else:
+            await self.send_frame(P.encode_frame(req_id, opcode, payload))
+
+    async def send_frame(self, frame: bytes | list) -> None:
+        """Write one reply frame (``bytes``, or a list of buffers the
+        transport sends without joining), then wait while the transport's
+        write buffer is above its high-water mark.  One coroutine of the
         connection writes at a time, so one waiter is enough."""
         if self._lost:
             raise ConnectionResetError("connection lost")
-        self.transport.write(P.encode_frame(req_id, opcode, payload))
+        if isinstance(frame, list):
+            self.transport.writelines(frame)
+        else:
+            self.transport.write(frame)
         if self._drained is not None:
             await self._drained
         if self._lost:
@@ -429,15 +457,17 @@ class FDBServer:
                 f"expected HELLO, got opcode {Op.NAMES.get(opcode, opcode)!r}"
             )
         P.decode_hello(cur)
-        ext = P.decode_hello_ext(cur)
+        ext = min(P.decode_hello_ext(cur), P.STREAM_EXT_VERSION)
         from ..config import schema_to_config
 
         spec = json.dumps(schema_to_config(self.fdb.schema))
         payload = P.pack_str(spec)
         if ext >= P.TRACE_EXT_VERSION:
-            # echo the extension level as an optional trailing u16 a v1
-            # client never reads — only a peer that advertised it gets it
-            payload += P.pack_u16(P.TRACE_EXT_VERSION)
+            # echo the extension level both ends speak as an optional
+            # trailing u16 a v1 client never reads — only a peer that
+            # advertised it gets it
+            payload += P.pack_u16(ext)
+            conn.ext = ext
         await conn.send(req_id, Op.OK, payload)
         self.wire_stats.record("wire_hello", shard=conn.name)
 
@@ -538,31 +568,36 @@ class FDBServer:
         req_id, opcode, _ = P.split_frame(frame.body)
         t0 = time.perf_counter()
         try:
-            payload = await loop.run_in_executor(self._executor, self._serve_op, frame)
-            resp_op = Op.OK
+            resp_op, payload = await loop.run_in_executor(
+                self._executor, self._serve_op, frame, conn
+            )
         except asyncio.CancelledError:
             raise
         except Exception as e:  # noqa: BLE001 — forwarded to the client
             payload, resp_op = P.encode_error(e), Op.ERR
         dt = time.perf_counter() - t0
         base = P.mask_op(opcode)[0]
+        nbytes = P.pieces_len(payload) if isinstance(payload, list) else len(payload)
         self.wire_stats.record(
             f"wire_{Op.NAMES.get(base, hex(base))}",
-            seconds=dt, nbytes_w=len(payload), shard=conn.name,
+            seconds=dt, nbytes_w=nbytes, shard=conn.name,
         )
         await conn.send(req_id, resp_op, payload)
 
     # --------------------------------------------------------- op execution
-    def _serve_op(self, frame: _Frame) -> bytes:
-        """Decode one request frame, run it against the FDB, encode the OK
-        payload.  Runs on the executor thread pool.  A TRACE_FLAG'd frame
-        carries a trace-context prefix: the op executes under a server span
-        parented to the client's wire span, so the client can stitch the
-        server-side time into ONE trace via the Op.TRACE round.  The span's
-        ``queued_s`` runs from the frame's body being read to this thread
-        starting the op; ``read_s`` and ``read_calls`` are that read."""
+    def _serve_op(self, frame: _Frame, conn: _Connection) -> tuple[int, bytes | list]:
+        """Decode one request frame, run it against the FDB, and return the
+        reply's last frame as ``(opcode, payload)``: ``OK``, or ``END`` after
+        the parts of a streamed reply, which this thread has sent; a read's
+        one-frame reply is a list of buffers (:func:`P.reply_pieces`).  Runs on
+        the executor thread pool.  A TRACE_FLAG'd frame carries a
+        trace-context prefix: the op executes under a server span parented
+        to the client's wire span, so the client can stitch the server-side
+        time into ONE trace via the Op.TRACE round.  The span's ``queued_s``
+        runs from the frame's body being read to this thread starting the
+        op; ``read_s`` and ``read_calls`` are that read."""
         t_run = time.perf_counter()
-        _, raw_op, cur = P.split_frame(frame.body)
+        req_id, raw_op, cur = P.split_frame(frame.body)
         opcode, traced = P.mask_op(raw_op)
         ctx = None
         if traced:
@@ -573,7 +608,7 @@ class FDBServer:
             # the extended STATS round: hand the accumulated server spans
             # to the client (drained — each round returns fresh spans)
             spans = [s.to_dict() for s in self.tracer.drain()]
-            return P.pack_str(json.dumps(spans))
+            return Op.OK, P.pack_str(json.dumps(spans))
         tr = self.tracer
         with tr.span(_SERVER_SPANS.get(opcode, "server.op"), remote_parent=ctx) as sp:
             if tr.enabled:
@@ -581,33 +616,55 @@ class FDBServer:
                 sp.set("queued_s", t_run - frame.t_read)
                 sp.set("read_s", frame.read_s)
                 sp.set("read_calls", frame.read_calls)
-            return self._dispatch_op(opcode, cur)
+            if opcode == Op.RETRIEVE_BATCH:
+                keys = P.decode_keys(cur)
+                return self._reply_fields(
+                    opcode, list(zip(keys, self.fdb.retrieve_batch(keys))), conn, req_id, sp
+                )
+            if opcode == Op.RETRIEVE_MANY:
+                fs = self.fdb.retrieve_many(P.decode_request(cur))
+                return self._reply_fields(
+                    opcode, list(zip(fs.keys, fs.handles())), conn, req_id, sp
+                )
+            return Op.OK, self._dispatch_op(opcode, cur)
+
+    def _reply_fields(self, opcode: int, handles: list, conn: _Connection, req_id: int,
+                      sp) -> tuple[int, bytes | list]:
+        """A read's reply to ``(key, handle)`` items: one frame, as the
+        payload's pieces; or, past the part bound to a peer that streams,
+        ``PART`` frames sent from this thread, each part read from the store
+        just before it is written, and the next read only once the
+        transport has drained (module docstring); then the ``END`` payload.
+        The payloads go to the transport as they were read, never joined
+        into a frame."""
+        sizes = [(k, None if h is None else h.size) for k, h in handles]
+        ranges = P.split_reply(opcode, sizes, P.part_bound(self._max_frame))
+        if len(ranges) == 1 or conn.ext < P.STREAM_EXT_VERSION:
+            return Op.OK, P.reply_pieces(opcode, _read_items(handles))
+        tr, stats = self.tracer, self.wire_stats
+        most = 0
+        for r in ranges:
+            t0 = time.perf_counter()
+            with tr.span(_PART_SPANS[opcode]) as part:
+                pieces = P.reply_pieces(opcode, _read_items(handles[r.start:r.stop]))
+                frame = [P.frame_head(req_id, Op.PART, P.pieces_len(pieces)), *pieces]
+                size = P.pieces_len(frame)
+                most = max(most, size)
+                if tr.enabled:
+                    part.set("bytes", size - 4)
+                    part.set("fields", len(r))
+                asyncio.run_coroutine_threadsafe(conn.send_frame(frame), self._loop).result()
+            stats.record("wire_part", seconds=time.perf_counter() - t0, nbytes_w=size,
+                         shard=conn.name)
+        with stats.lock:
+            stats.counters["wire_part_buffered_max"] = max(
+                stats.counters["wire_part_buffered_max"], most)
+        if tr.enabled:
+            sp.set("parts", len(ranges))
+            sp.set("buffered_max", most)
+        return Op.END, P.encode_end(len(ranges), len(handles))
 
     def _dispatch_op(self, opcode: int, cur: Cursor) -> bytes:
-        if opcode == Op.RETRIEVE_BATCH:
-            keys = P.decode_keys(cur)
-            payloads: list[bytes | None] = []
-            for h in self.fdb.retrieve_batch(keys):
-                if h is None:
-                    payloads.append(None)
-                else:
-                    try:
-                        payloads.append(h.read())
-                    finally:
-                        h.close()
-            return P.encode_handles(payloads)
-        if opcode == Op.RETRIEVE_MANY:
-            fs = self.fdb.retrieve_many(P.decode_request(cur))
-            items: list[tuple] = []
-            for key, h in zip(fs.keys, fs.handles()):
-                if h is None:
-                    items.append((key, None))
-                else:
-                    try:
-                        items.append((key, h.read()))
-                    finally:
-                        h.close()
-            return P.encode_fieldset(items)
         if opcode == Op.LIST:
             return P.encode_listing(self.fdb.list(P.decode_request(cur)))
         if opcode == Op.WIPE:
@@ -624,6 +681,21 @@ class FDBServer:
         if opcode == Op.HELLO:
             raise ProtocolError("duplicate handshake on an established connection")
         raise ProtocolError(f"unknown opcode {opcode:#x}")
+
+
+def _read_items(handles) -> list[tuple]:
+    """``(key, payload)`` of ``(key, handle)`` items, each handle read and
+    closed (an absent field stays ``None``)."""
+    items: list[tuple] = []
+    for key, h in handles:
+        if h is None:
+            items.append((key, None))
+        else:
+            try:
+                items.append((key, h.read()))
+            finally:
+                h.close()
+    return items
 
 
 def _base_op(frame: _Frame) -> int:

@@ -315,7 +315,13 @@ class SelectFDB(FDBClient):
         migration flip may have moved the key (and removed the source
         copy), so a miss from a now-stale tier must be retried against the
         CURRENT owner before it counts as absent."""
-        h = client.retrieve(key)
+        return self._rerouted(key, client, client.retrieve(key))
+
+    def _rerouted(self, key: Key | Mapping[str, str], client: FDBClient,
+                  h: DataHandle | None) -> DataHandle | None:
+        """``client``'s answer ``h`` for ``key``; a miss from a tier that
+        may have just lost the key to a migration flip is re-routed once
+        before it counts as absent."""
         if h is None:
             now = self.route(key)
             if now is not None and now is not client:
@@ -345,14 +351,41 @@ class SelectFDB(FDBClient):
                         tsp.set("n_keys", len(idxs))
                     results = client.retrieve_batch([keys[i] for i in idxs])
                 for i, r in zip(idxs, results):
-                    if r is None:
-                        # miss from a tier that may have just lost the key
-                        # to a migration flip — re-route before answering
-                        now = self.route(keys[i])
-                        if now is not None and now is not client:
-                            r = now.retrieve(keys[i])
-                    out[i] = r
+                    out[i] = self._rerouted(keys[i], client, r)
             return out
+
+    def retrieve_batch_parts(self, keys: Sequence[Key | Mapping[str, str]]):
+        """Each tier's parts in turn, positions mapped back to ``keys`` and
+        misses re-routed as in :meth:`retrieve_batch`; keys that no tier
+        owns come first, as one part of absent fields.  A part's ``more`` is
+        set too while another tier's parts follow.  Every tier's part source
+        is made here, on the caller's thread."""
+        groups: dict[int, tuple[FDBClient, list[int]]] = {}
+        unrouted: list[int] = []
+        for i, key in enumerate(keys):
+            client = self.route(key)
+            if client is None:
+                unrouted.append(i)
+            else:
+                groups.setdefault(id(client), (client, []))[1].append(i)
+        sources = [(client, idxs, client.retrieve_batch_parts([keys[i] for i in idxs]))
+                   for client, idxs in groups.values()]
+        return self._tier_parts(keys, unrouted, sources)
+
+    def _tier_parts(self, keys, unrouted: list[int], sources: list):
+        if unrouted:
+            yield unrouted, [None] * len(unrouted), bool(sources)
+        for t, (client, idxs, parts) in enumerate(sources):
+            later = t + 1 < len(sources)  # another tier's parts follow
+            try:
+                for pos, handles, more in parts:
+                    yield ([idxs[p] for p in pos],
+                           [self._rerouted(keys[idxs[p]], client, h) for p, h in zip(pos, handles)],
+                           more or later)
+            finally:
+                close = getattr(parts, "close", None)
+                if close is not None:
+                    close()
 
     def _list(self, request: Request) -> Iterator[ListEntry]:
         """Merged listing across every tier the request could touch.  Tiers

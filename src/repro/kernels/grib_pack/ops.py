@@ -39,26 +39,34 @@ def payload_dtype(nbits: int) -> np.dtype:
 
 @partial(jax.jit, static_argnames=("nbits", "interpret"))
 def grib_pack(x: jax.Array, *, nbits: int = 16, interpret: bool | None = None):
-    """x: (F, H, W) float -> (codes (F,H,W) int32, ref (F,), scale (F,))."""
+    """x: (F, H, W) float -> (codes (F,H,W), ref (F,), scale (F,)).  The
+    kernel's int32 codes are narrowed on the device to their wire container
+    (:func:`payload_dtype`), so the host copies back the wire's bytes and
+    nothing wider."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     ref, scale, inv_scale = field_stats(x, nbits)
     codes = grib_pack_call(x, ref, inv_scale, nbits=nbits, interpret=interpret)
-    return codes, ref, scale
+    return codes.astype(payload_dtype(nbits)), ref, scale
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def grib_unpack(codes: jax.Array, ref: jax.Array, scale: jax.Array, *, interpret: bool | None = None):
+def grib_unpack(codes, ref: jax.Array, scale: jax.Array, *, interpret: bool | None = None):
+    """codes: (F, H, W) int32 or an unsigned wire container, or a sequence
+    of F (H, W) such arrays, stacked and widened on the device, so the host
+    hands over each field's codes where they lie -> (F, H, W) float32."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return grib_unpack_call(codes, ref, scale, interpret=interpret)
+    if isinstance(codes, (tuple, list)):
+        codes = jnp.stack(codes)
+    return grib_unpack_call(codes.astype(jnp.int32), ref, scale, interpret=interpret)
 
 
 def pack_to_bytes(x: np.ndarray, nbits: int = 16) -> tuple[bytes, dict]:
     """Host-side convenience: one field (H, W) -> GRIB-ish byte payload."""
     dtype = payload_dtype(nbits)
     codes, ref, scale = grib_pack(jnp.asarray(x)[None], nbits=nbits)
-    arr = np.asarray(codes[0]).astype(dtype)
+    arr = np.asarray(codes[0])
     meta = {
         "ref": float(ref[0]),
         "scale": float(scale[0]),
@@ -83,9 +91,9 @@ def unpack_from_bytes(payload: bytes, meta: dict) -> np.ndarray:
             f"({h}, {w}) field of {dtype.name} codes ({expected} bytes) — "
             "payload and meta do not belong together"
         )
-    codes = np.frombuffer(payload, dtype=dtype).reshape(h, w).astype(np.int32)
+    codes = np.frombuffer(payload, dtype=dtype).reshape(1, h, w)
     out = grib_unpack(
-        jnp.asarray(codes)[None],
+        codes,
         jnp.asarray([meta["ref"]], dtype=jnp.float32),
         jnp.asarray([meta["scale"]], dtype=jnp.float32),
     )

@@ -150,6 +150,12 @@ class Span:
         self.tracer._pop(self)
         return False
 
+    def end(self) -> None:
+        """Finish a span that was never entered: one held open across a
+        generator's yields, which must sit on no thread's stack of open
+        spans (its children name it as their ``parent``)."""
+        self.tracer._pop(self)
+
     # -- serialisation -----------------------------------------------------
 
     def to_dict(self) -> dict[str, Any]:
@@ -360,6 +366,9 @@ class _NullSpan:
         return False
 
     def set(self, key: str, value: Any) -> None:
+        pass
+
+    def end(self) -> None:
         pass
 
     @property

@@ -56,3 +56,21 @@ def test_unpack_compiles_for_v5e(one_chip, shape):
     codes = _spec(shape, jnp.int32, one_chip)
     compiled = grib_unpack.lower(codes, vec, vec, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint16, jnp.uint32])
+@pytest.mark.parametrize("shape", [(10, 1801, 3600), (20, 1801, 3600), HAMMER_SHAPE])
+def test_unpack_of_wire_container_codes_compiles_for_v5e(one_chip, shape, dtype):
+    vec = _spec(shape[:1], jnp.float32, one_chip)
+    codes = _spec(shape, dtype, one_chip)
+    compiled = grib_unpack.lower(codes, vec, vec, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint16, jnp.uint32])
+@pytest.mark.parametrize("f", [1, 10, 20])
+def test_unpack_of_per_field_codes_compiles_for_v5e(one_chip, f, dtype):
+    vec = _spec((f,), jnp.float32, one_chip)
+    codes = tuple(_spec((1801, 3600), dtype, one_chip) for _ in range(f))
+    compiled = grib_unpack.lower(codes, vec, vec, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()

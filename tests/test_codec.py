@@ -35,12 +35,13 @@ from repro.core.codec import (
 from repro.core.config import ConfigError
 from repro.core.daos import DaosEngine
 from repro.kernels.grib_pack import (
+    grib_pack,
     grib_unpack,
     pack_to_bytes,
     payload_dtype,
     unpack_from_bytes,
 )
-from repro.kernels.grib_pack.ref import field_stats, pack_ref
+from repro.kernels.grib_pack.ref import field_stats, pack_ref, unpack_ref
 from repro.metrics.iostats import IOStats
 from repro.obs import Tracer, install_tracer
 
@@ -170,6 +171,46 @@ class TestWireFormat:
 # ---------------------------------------------------------------------------
 
 class TestEncodeDecode:
+    @pytest.mark.parametrize("nbits", NBITS_SWEEP)
+    @pytest.mark.parametrize("given", ["array", "list", "float64"])
+    def test_payloads_hold_the_kernels_codes_at_container_width(self, nbits, given):
+        fields = temperature_fields(np.random.default_rng(11), 3, 16, 128)
+        arg = {"array": fields, "list": list(fields), "float64": fields.astype(np.float64)}[given]
+        payloads = encode_fields(arg, nbits=nbits)
+        codes, ref, scale = grib_pack(fields, nbits=nbits)
+        dtype = payload_dtype(nbits)
+        for j, p in enumerate(payloads):
+            assert type(p) is bytes and len(p) == wire_size((16, 128), nbits)
+            hdr = parse_header(p)
+            assert (hdr.ref, hdr.scale) == (float(ref[j]), float(scale[j]))
+            assert p[CODEC_HEADER_SIZE:] == np.asarray(codes[j]).astype(dtype).tobytes()
+
+    def test_payloads_from_codes_laid_out_field_minor(self, monkeypatch):
+        """A device-to-host array may come back in another layout than C
+        order (the chip returns 16-bit batches so): the payloads hold the
+        same bytes."""
+        from repro.core import codec
+
+        fields = temperature_fields(np.random.default_rng(13), 4, 16, 128)
+        want = encode_fields(fields, nbits=16)
+        real = codec.grib_pack
+
+        def field_minor(x, **kw):
+            codes, ref, scale = real(x, **kw)
+            c = np.asarray(codes)
+            return np.moveaxis(np.ascontiguousarray(np.moveaxis(c, 0, -1)), -1, 0), ref, scale
+
+        monkeypatch.setattr(codec, "grib_pack", field_minor)
+        assert not field_minor(fields, nbits=16)[0][1].flags.c_contiguous
+        assert encode_fields(fields, nbits=16) == want
+
+    def test_take_fields_views_a_run_and_copies_the_rest(self):
+        fields = temperature_fields(np.random.default_rng(12), 6, 8, 128)
+        run, scattered = take_fields(fields, [2, 3, 4]), take_fields(fields, [0, 2, 5])
+        assert np.shares_memory(run, fields) and np.array_equal(run, fields[2:5])
+        assert not np.shares_memory(scattered, fields)
+        assert np.array_equal(scattered, fields[[0, 2, 5]])
+
     def test_one_pack_launch_per_uniform_batch(self):
         fields = temperature_fields(np.random.default_rng(7), 9, 16, 128)
         tr = Tracer()
@@ -586,3 +627,227 @@ class TestHammerCodec:
             hammer.main()
         assert exc.value.code == 2
         assert "--codec-nbits" in capsys.readouterr().err
+
+
+# ---------------------------------------------------------------------------
+# streamed replies: the whole set decodes part by part as the parts land
+# ---------------------------------------------------------------------------
+
+#: a max_frame that holds one to three fields of a 16x128 grid
+SMALL_FRAME = 16 << 10
+
+
+@pytest.fixture
+def streamed_tiers(tmp_path):
+    """The benchmark's tiered tree at a small max_frame: member 0 on a
+    16-bit DAOS tier, every other member on a 24-bit POSIX tier, each
+    behind its own server."""
+    from repro.core import FDBServer, RemoteFDB
+
+    def tier(inner, nbits):
+        server = FDBServer(inner, owns_fdb=True, max_frame=SMALL_FRAME)
+        server.start()
+        return CodecFDB(RemoteFDB(server=server, max_frame=SMALL_FRAME), nbits=nbits)
+
+    fdb = SelectFDB(
+        [("number=0", tier(make_fdb("daos", schema=NWP_SCHEMA_DAOS, engine=DaosEngine()), 16))],
+        default=tier(make_fdb("posix", schema=NWP_SCHEMA_POSIX, root=str(tmp_path / "cold")), 24),
+    )
+    yield fdb
+    fdb.close()
+
+
+def archive_column(fdb, member: str, nbits: int, n: int = 24, seed: int = 0):
+    """``n`` fields of one member archived one a call; their keys, source
+    fields and the key->bytes reference of their payloads."""
+    keys = [example_key(number=member, step=str(s)) for s in range(n)]
+    fields = temperature_fields(np.random.default_rng(seed), n, 16, 128)
+    for k, f in zip(keys, fields):
+        fdb.archive_fields([k], f[None])
+    fdb.flush()
+    ref = {k: encode_fields(f[None], nbits=nbits)[0] for k, f in zip(keys, fields)}
+    return keys, fields, ref
+
+
+def column_request(keys) -> dict:
+    return {**dict(keys[0]), "number": sorted({k["number"] for k in keys}),
+            "step": sorted({k["step"] for k in keys}, key=int)}
+
+
+@pytest.mark.parametrize("member,nbits", [("0", 16), ("5", 24)])
+class TestStreamedDecode:
+    def test_parts_decode_as_they_land_into_one_array(self, streamed_tiers, member, nbits):
+        from repro.core.remote import protocol as P
+
+        fdb = streamed_tiers
+        keys, fields, ref = archive_column(fdb, member, nbits)
+        # byte for byte before decoding: the streamed parts, the tier's
+        # in-process store and the key->bytes reference agree
+        streamed = [h.read() for _, hs, _ in fdb.retrieve_batch_parts(keys) for h in hs]
+        tier = fdb.route(keys[0]).inner
+        assert streamed == [ref[k] for k in keys] == [tier._server.fdb.read(k) for k in keys]
+
+        tr = Tracer()
+        install_tracer(fdb, tr)
+        got = fdb.retrieve_fields(column_request(keys))
+        arr = got.arrays()
+        wire = [s for s in tr.spans() if s.name == "wire.retrieve_batch"]
+        parts = len(P.split_reply(P.Op.RETRIEVE_BATCH, [(k, len(p)) for k, p in ref.items()],
+                                  P.part_bound(SMALL_FRAME)))
+        assert len(wire) == 1 and wire[0].attrs["parts"] == parts >= 8
+        # one unpack launch a part, into one array that arrays() hands back
+        assert launches(tr)["unpack"] == parts
+        assert got.arrays() is arr and arr.shape == fields.shape
+        # the decoded arrays are the reference decode of the stored codes
+        codes = np.stack([
+            np.frombuffer(p, dtype=payload_dtype(nbits), offset=CODEC_HEADER_SIZE)
+            .reshape(16, 128).astype(np.int32) for p in ref.values()])
+        hdrs = [parse_header(p) for p in ref.values()]
+        expected = np.asarray(unpack_ref(
+            jnp.asarray(codes), jnp.asarray([h.ref for h in hdrs], jnp.float32),
+            jnp.asarray([h.scale for h in hdrs], jnp.float32)))
+        assert np.abs(arr - expected).max() <= 2 * np.spacing(np.float32(np.abs(expected).max()))
+        assert np.array_equal(arr, np.stack(decode_payloads(list(ref.values()))))
+        # the receiving side holds at most two parts at once
+        per_field = len(next(iter(ref.values())))
+        most = max(len(r) for r in P.split_reply(
+            P.Op.RETRIEVE_BATCH, [(k, len(p)) for k, p in ref.items()], P.part_bound(SMALL_FRAME)))
+        assert 0 < got.buffered_max <= 2 * most * per_field
+        counters = fdb.stats_snapshot()["counters"]
+        assert counters["unpack_part_buffered_max"] == got.buffered_max
+
+    def test_an_error_among_the_parts_raises(self, streamed_tiers, member, nbits, monkeypatch):
+        import repro.core.remote.server as server_mod
+        from repro.core import RemoteError
+
+        fdb = streamed_tiers
+        keys, _, _ = archive_column(fdb, member, nbits)
+        read_items = server_mod._read_items
+        calls = []
+
+        def failing_third_part(handles):
+            calls.append(1)
+            if len(calls) == 3:
+                raise RuntimeError("store failed mid reply")
+            return read_items(handles)
+
+        monkeypatch.setattr(server_mod, "_read_items", failing_third_part)
+        with pytest.raises(RemoteError, match="store failed mid reply"):
+            fdb.retrieve_fields(column_request(keys)).arrays()
+
+    def test_a_decoded_set_is_not_fetched_again(self, streamed_tiers, member, nbits):
+        fdb = streamed_tiers
+        keys, fields, _ = archive_column(fdb, member, nbits)
+        server = fdb.route(keys[0]).inner._server
+
+        def served() -> int:
+            return server.wire_stats.snapshot()["ops"].get("wire_retrieve_batch", 0)
+
+        before = served()
+        got = fdb.retrieve_fields(column_request(keys))
+        arr = got.arrays()
+        assert got.arrays() is arr
+        assert got.missing() == []
+        assert all(a.base is arr for a in got.read_all().values())
+        assert dict(got.items()).keys() == set(keys)
+        assert served() - before == 1
+        # the parts went by: the FieldSet keeps no handle of them
+        assert repr(got._fs) == f"FieldSet({len(keys)} fields, 0 resolved)"
+
+    def test_a_set_decoded_in_part_decodes_the_rest_once(self, streamed_tiers, member, nbits):
+        fdb = streamed_tiers
+        keys, fields, ref = archive_column(fdb, member, nbits)
+        server = fdb.route(keys[0]).inner._server
+        before = server.wire_stats.snapshot()["ops"].get("wire_retrieve_batch", 0)
+        tr = Tracer()
+        install_tracer(fdb, tr)
+        got = DecodedFieldSet(fdb.retrieve_many(column_request(keys)), chunk=5, tracer=tr)
+        first = got[keys[0]]
+        arr = got.arrays()
+        assert arr[0] is not first and np.array_equal(arr[0], first)
+        assert np.array_equal(arr, np.stack(decode_payloads([ref[k] for k in keys])))
+        assert got.missing() == []
+        # the FieldSet's first chunk of 64 keys fetched the whole column
+        # once; the decoder's first chunk of 5, then the rest, in a launch
+        # each, from those handles
+        assert server.wire_stats.snapshot()["ops"]["wire_retrieve_batch"] - before == 1
+        assert launches(tr)["unpack"] == 2
+
+
+@pytest.mark.parametrize("member,nbits", [("0", 16), ("5", 24)])
+@pytest.mark.parametrize("n,feeds", [(1, 0), (24, 1)])
+def test_only_a_reply_that_goes_on_takes_a_thread(streamed_tiers, member, nbits, n, feeds,
+                                                  monkeypatch):
+    """A one-frame reply decodes on the caller's thread alone; the parts of
+    a streamed reply after the first are taken on one thread besides."""
+    import repro.core.codec as codec_mod
+
+    made = []
+    part_feed = codec_mod._PartFeed
+
+    def counted(*args):
+        made.append(1)
+        return part_feed(*args)
+
+    monkeypatch.setattr(codec_mod, "_PartFeed", counted)
+    fdb = streamed_tiers
+    keys, fields, ref = archive_column(fdb, member, nbits, n=n)
+    got = fdb.retrieve_fields(column_request(keys))
+    assert np.array_equal(got.arrays(), np.stack(decode_payloads([ref[k] for k in keys])))
+    assert len(made) == feeds
+    assert (got.buffered_max > 0) == bool(feeds)
+
+
+def test_a_request_across_both_tiers_decodes_in_key_order(streamed_tiers):
+    fdb = streamed_tiers
+    hot_keys, hot, _ = archive_column(fdb, "0", 16, n=10, seed=1)
+    cold_keys, cold, _ = archive_column(fdb, "5", 24, n=10, seed=2)
+    got = fdb.retrieve_fields(column_request(hot_keys + cold_keys))
+    arr = got.arrays()
+    order = {k: i for i, k in enumerate(got.keys)}
+    for keys, fields, nbits in ((hot_keys, hot, 16), (cold_keys, cold, 24)):
+        for k, f in zip(keys, fields):
+            # a quantisation step, or float32's resolution near the
+            # values where 24-bit steps are finer
+            step = (f.max() - f.min()) / ((1 << nbits) - 1)
+            assert np.abs(arr[order[k]] - f).max() <= max(step, 4 * np.spacing(np.abs(f).max()))
+
+
+def test_concurrent_streamed_reads_stay_whole(streamed_tiers):
+    """More readers than cores, each taking streamed whole-set reads with a
+    short switch interval: every answer is whole and right, and no reader
+    holds more than two parts."""
+    import os
+    import threading
+
+    from repro.core.remote import protocol as P
+
+    fdb = streamed_tiers
+    keys, fields, ref = archive_column(fdb, "0", 16, n=12, seed=3)
+    expected = np.stack(decode_payloads(list(ref.values())))
+    part = max(len(r) for r in P.split_reply(
+        P.Op.RETRIEVE_BATCH, [(k, len(p)) for k, p in ref.items()], P.part_bound(SMALL_FRAME)))
+    field = len(next(iter(ref.values())))
+    errors: list = []
+
+    def reader():
+        try:
+            for _ in range(3):
+                got = fdb.retrieve_fields(column_request(keys))
+                assert np.array_equal(got.arrays(), expected)
+                assert got.buffered_max <= 2 * part * field
+        except BaseException as e:  # noqa: BLE001 -- reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=reader) for _ in range((os.cpu_count() or 2) + 2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]

@@ -131,6 +131,26 @@ class TestGribPack:
         # rounding boundaries can flip ±1 code
         assert np.abs(np.asarray(codes) - np.asarray(expected)).max() <= 1
 
+    @pytest.mark.parametrize("nbits", [8, 16, 24])
+    def test_codes_narrowed_to_their_container_on_the_device(self, nbits):
+        from repro.kernels.grib_pack import payload_dtype
+
+        x = jax.random.normal(jax.random.PRNGKey(5), (3, 32, 128)) * 40 + 250.0
+        narrow, ref, scale = grib_pack(x, nbits=nbits, interpret=True)
+        lo, _, inv = field_stats(x, nbits)
+        wide = np.asarray(pack_ref(x, lo, inv, nbits))
+        assert narrow.dtype == payload_dtype(nbits) and wide.dtype == np.int32
+        # rounding boundaries can flip one code
+        assert np.abs(np.asarray(narrow).astype(np.int64) - wide).max() <= 1
+        # unpack widens a container on the device, and stacks fields handed
+        # over one by one: the same floats every way
+        wide = np.asarray(narrow).astype(np.int32)
+        want = np.asarray(grib_unpack(wide, ref, scale, interpret=True))
+        fields = tuple(np.asarray(narrow))
+        for codes in (narrow, fields):
+            np.testing.assert_array_equal(
+                np.asarray(grib_unpack(codes, ref, scale, interpret=True)), want)
+
     def test_constant_field(self):
         x = jnp.full((1, 32, 128), 5.0)
         codes, ref, scale = grib_pack(x, interpret=True)

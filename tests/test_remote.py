@@ -24,6 +24,7 @@ import threading
 import time
 import tracemalloc
 
+import numpy as np
 import pytest
 
 import test_select
@@ -158,6 +159,57 @@ class TestProtocol:
             assert got == want
             payloads = [p[1] if isinstance(p, tuple) else p for p in got]
             assert all(p is None or type(p) is bytes for p in payloads)
+
+    def test_handle_views_read_the_frame_uncopied(self):
+        handles = [b"abc", None, b"", b"defg"]
+        buf = bytearray(P.encode_frame(1, P.Op.OK, P.encode_handles(handles))[4:])
+        got = P.decode_handles(P.split_frame(buf)[2], views=True)
+        assert got == handles
+        views = [g for g in got if g is not None]
+        assert all(type(v) is memoryview and v.readonly for v in views)
+        buf[-4:] = b"wxyz"  # a view is the frame's bytes, not a copy of them
+        assert got[3] == b"wxyz"
+
+    def test_frames_sent_as_pieces_are_the_joined_frames(self):
+        items = [(ident(step=str(s)), np.arange(s + 3, dtype=np.uint16)) for s in range(3)]
+        joined = [(k, v.tobytes()) for k, v in items]
+        pieces = P.archive_batch_pieces(items)
+        frame = b"".join([P.frame_head(9, P.Op.ARCHIVE_BATCH, P.pieces_len(pieces)), *pieces])
+        assert frame == P.encode_frame(9, P.Op.ARCHIVE_BATCH, P.encode_archive_batch(joined))
+        for op in (P.Op.RETRIEVE_BATCH, P.Op.RETRIEVE_MANY):
+            reply = [(ident(step="1"), b"xy"), (ident(step="2"), None)]
+            pieces = P.reply_pieces(op, reply)
+            body = P.encode_handles([b"xy", None]) if op == P.Op.RETRIEVE_BATCH \
+                else P.encode_fieldset(reply)
+            assert b"".join(pieces) == body
+
+    def test_a_connection_sends_pieces_past_the_socket_buffer_in_order(self):
+        from repro.core.remote.client import _Conn
+
+        a, b = socket.socketpair()
+        a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        rng = np.random.default_rng(1)
+        pieces = [b"head", memoryview(b""), rng.integers(0, 255, 300_000, dtype=np.uint8),
+                  bytearray(b"mid"), rng.integers(0, 1 << 16, 70_000, dtype=np.uint16)]
+        pieces += [bytes([i % 256]) * (i + 1) for i in range(700)]  # more buffers than one sendmsg takes
+        want = b"".join(bytes(memoryview(p).cast("B")) for p in pieces)
+        got = bytearray()
+
+        def drain():
+            while len(got) < len(want):
+                got.extend(b.recv(1 << 16))
+
+        t = threading.Thread(target=drain)
+        t.start()
+        conn = _Conn.__new__(_Conn)
+        conn.sock = a
+        try:
+            conn._send(pieces)
+            t.join(timeout=30)
+        finally:
+            a.close()
+            b.close()
+        assert bytes(got) == want
 
 
 # ---------------------------------------------------------------------------
@@ -749,3 +801,248 @@ class TestFieldResolution:
         fs = FieldSet(self.KEYS, lambda ks: [None] * len(ks), batch_size=2)
         assert fs.handles() == [None] * 4
         assert fs.missing() == self.KEYS
+
+
+# ---------------------------------------------------------------------------
+# Streamed replies: a read past the part bound comes in parts
+# ---------------------------------------------------------------------------
+
+#: the benchmark's tiers: 16-bit codes over DAOS, 24-bit over POSIX
+STREAM_TIERS = [("daos", 16), ("posix", 24)]
+#: a max_frame that holds one to three fields of a 16x128 grid
+SMALL_FRAME = 16 << 10
+
+
+def _column(fdb, nbits: int, n: int = 24) -> dict:
+    """``n`` fields' worth of codec-sized payloads of one tier, archived
+    one a frame: the key->bytes reference."""
+    from repro.core import wire_size
+
+    rng = np.random.default_rng(nbits)
+    ref = {ident(step=str(s)): rng.bytes(wire_size((16, 128), nbits)) for s in range(n)}
+    for k, p in ref.items():
+        fdb.archive_batch([(k, p)])
+    fdb.flush()
+    return ref
+
+
+def _parts(opcode, ref: dict, bound: int) -> int:
+    return len(P.split_reply(opcode, [(k, len(p)) for k, p in ref.items()], bound))
+
+
+def _frame_op(frame) -> int:
+    """The opcode of a frame the server writes: ``bytes``, or a list of
+    buffers whose first is the length prefix and header."""
+    return bytes(frame[0] if isinstance(frame, list) else frame[:9])[8]
+
+
+def _served(server, op: str, n: int) -> int:
+    """The server's count of ``op``, once it has reached ``n`` (an op is
+    counted after its reply)."""
+    deadline = time.time() + 10
+    while server.wire_stats.snapshot()["ops"].get(op, 0) < n and time.time() < deadline:
+        time.sleep(0.01)
+    return server.wire_stats.snapshot()["ops"].get(op, 0)
+
+
+@pytest.mark.parametrize("backend,nbits", STREAM_TIERS)
+class TestStreamedReplies:
+    def _pair(self, servers, backend, tmp_path, **kw):
+        server = start_server(servers, backend, tmp_path, max_frame=SMALL_FRAME)
+        return server, connect(server, max_frame=SMALL_FRAME, **kw)
+
+    def test_streamed_reads_match_the_reference_and_the_tree(
+        self, backend, nbits, tmp_path, servers
+    ):
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            keys = list(ref)
+            got = [h.read() for h in fdb.retrieve_batch(keys)]
+            assert got == [ref[k] for k in keys] == [server.fdb.read(k) for k in keys]
+            request = {**dict(keys[0]), "step": [k["step"] for k in keys]}
+            assert fdb.retrieve_many(request).read_all() == ref
+        bound = P.part_bound(SMALL_FRAME)
+        batch, many = _parts(P.Op.RETRIEVE_BATCH, ref, bound), _parts(P.Op.RETRIEVE_MANY, ref, bound)
+        assert batch >= 8 and many >= 8
+        snap = server.wire_stats.snapshot()
+        assert snap["ops"]["wire_part"] == batch + many
+        # the server holds one part at a time: never more than two
+        assert 0 < snap["counters"]["wire_part_buffered_max"] <= 2 * (SMALL_FRAME + 4)
+
+    def test_parts_read_as_views_and_a_batch_as_bytes(self, backend, nbits, tmp_path, servers):
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            keys = list(ref)
+            parts = list(fdb.retrieve_batch_parts(keys))
+            assert len(parts) >= 8
+            views = [h.read() for _, handles, _ in parts for h in handles]
+            assert all(type(v) is memoryview for v in views)
+            assert views == [ref[k] for k in keys]
+            assert all(type(h.read()) is bytes for h in fdb.retrieve_batch(keys))
+
+    def test_parts_are_handed_on_as_they_land(self, backend, nbits, tmp_path, servers):
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            keys = list(ref)[::-1]
+            parts = list(fdb.retrieve_batch_parts(keys))
+            assert len(parts) == _parts(P.Op.RETRIEVE_BATCH, ref, P.part_bound(SMALL_FRAME))
+            assert [p for pos, _, _ in parts for p in pos] == list(range(len(keys)))
+            assert [h.read() for _, hs, _ in parts for h in hs] == [ref[k] for k in keys]
+            # every part says the reply goes on: its END frame is still to come
+            assert all(more for _, _, more in parts)
+
+    def test_err_partway_raises_remote_error(
+        self, backend, nbits, tmp_path, servers, monkeypatch
+    ):
+        import repro.core.remote.server as server_mod
+
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            calls = []
+            read_items = server_mod._read_items
+
+            def failing_second_part(handles):
+                calls.append(len(handles))
+                if len(calls) == 2:
+                    raise RuntimeError("store failed mid reply")
+                return read_items(handles)
+
+            monkeypatch.setattr(server_mod, "_read_items", failing_second_part)
+            with pytest.raises(RemoteError, match="store failed mid reply"):
+                fdb.retrieve_batch(list(ref))
+            assert fdb.wire_stats.snapshot()["ops"].get("remote_retry", 0) == 0
+            # the run ended cleanly: the connection serves the next request
+            k = next(iter(ref))
+            assert fdb.read(k) == ref[k]
+
+    def test_truncated_run_raises_protocol_error(
+        self, backend, nbits, tmp_path, servers, monkeypatch
+    ):
+        import repro.core.remote.server as server_mod
+
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            send_frame = server_mod._Connection.send_frame
+
+            async def cut_after_first_part(self, frame):
+                await send_frame(self, frame)
+                if _frame_op(frame) == P.Op.PART:
+                    self.transport.close()
+
+            monkeypatch.setattr(server_mod._Connection, "send_frame", cut_after_first_part)
+            with pytest.raises(ProtocolError, match="closed the connection"):
+                fdb.retrieve_batch(list(ref))
+            # past the first part, a fault is not retried
+            assert fdb.wire_stats.snapshot()["ops"].get("remote_retry", 0) == 0
+            assert _served(server, "wire_retrieve_batch", 1) == 1
+
+    def test_a_fault_before_the_first_part_is_retried(
+        self, backend, nbits, tmp_path, servers, monkeypatch
+    ):
+        import repro.core.remote.server as server_mod
+
+        server, fdb = self._pair(servers, backend, tmp_path)
+        with fdb:
+            ref = _column(fdb, nbits)
+            send_frame = server_mod._Connection.send_frame
+            cut = []
+
+            async def cut_before_first_part(self, frame):
+                if _frame_op(frame) == P.Op.PART and not cut:
+                    cut.append(self.name)
+                    self.transport.close()
+                    raise ConnectionResetError("cut before the first part")
+                await send_frame(self, frame)
+
+            monkeypatch.setattr(server_mod._Connection, "send_frame", cut_before_first_part)
+            got = [h.read() for h in fdb.retrieve_batch(list(ref))]
+            assert got == list(ref.values())
+            assert fdb.wire_stats.snapshot()["ops"]["remote_retry"] == 1
+
+    def test_a_frame_past_max_frame_is_not_retried(self, backend, nbits, tmp_path, servers):
+        server = start_server(servers, backend, tmp_path)
+        with connect(server) as writer:
+            ref = _column(writer, nbits, n=2)
+        # a client whose max_frame is below one field: the reply is one
+        # frame it refuses, and sending the request again would only
+        # rebuild that frame
+        with connect(server, max_frame=4096, retries=2) as fdb:
+            with pytest.raises(P.FrameTooLarge, match="exceeds"):
+                fdb.retrieve_batch(list(ref))
+            assert fdb.wire_stats.snapshot()["ops"].get("remote_retry", 0) == 0
+        assert _served(server, "wire_retrieve_batch", 1) == 1
+
+
+class TestStreamNegotiation:
+    def _served_column(self, servers, tmp_path):
+        server = start_server(servers, "daos", tmp_path)
+        with connect(server) as fdb:
+            ref = _column(fdb, 16, n=6)
+        return server, ref
+
+    def _raw_frames(self, server, keys, hello: bytes) -> list[bytes]:
+        """Every frame of the reply to one RETRIEVE_BATCH, raw, up to the
+        one that ends it."""
+        sock = socket.create_connection(server.addr, timeout=30)
+        try:
+            sock.sendall(P.encode_frame(0, P.Op.HELLO, hello))
+            assert _recv_frame(sock)[1] == P.Op.OK
+            sock.sendall(P.encode_frame(5, P.Op.RETRIEVE_BATCH, P.encode_keys(keys)))
+            frames = []
+            while True:
+                hdr = b""
+                while len(hdr) < 4:
+                    hdr += sock.recv(4 - len(hdr))
+                body = b""
+                while len(body) < P.frame_length(hdr):
+                    body += sock.recv(P.frame_length(hdr) - len(body))
+                frames.append(hdr + body)
+                if body[4] != P.Op.PART:
+                    return frames
+        finally:
+            sock.close()
+
+    def test_a_reply_under_the_bound_is_todays_single_frame(self, servers, tmp_path):
+        server, ref = self._served_column(servers, tmp_path)
+        frames = self._raw_frames(server, list(ref), P.encode_hello())
+        assert frames == [P.encode_frame(5, P.Op.OK, P.encode_handles(list(ref.values())))]
+
+    def test_only_a_peer_that_negotiated_streaming_gets_parts(
+        self, servers, tmp_path, monkeypatch
+    ):
+        server, ref = self._served_column(servers, tmp_path)
+        monkeypatch.setattr(P, "PART_BYTES", 3 * len(next(iter(ref.values()))))
+        # a level-2 peer (tracing, no streaming) gets the whole reply as one
+        # frame, as before
+        old = self._raw_frames(server, list(ref), P.encode_hello(P.TRACE_EXT_VERSION))
+        assert old == [P.encode_frame(5, P.Op.OK, P.encode_handles(list(ref.values())))]
+        new = self._raw_frames(server, list(ref), P.encode_hello())
+        ops = [f[8] for f in new]
+        assert ops == [P.Op.PART] * 3 + [P.Op.END]
+        payloads = [p for f in new[:-1] for p in P.decode_handles(P.split_frame(f[4:])[2])]
+        assert payloads == list(ref.values())
+        assert P.decode_end(P.split_frame(new[-1][4:])[2]) == (3, len(ref))
+
+    def test_the_benchmark_replies_stay_single_frames(self):
+        """The largest reply of the 8-level cells (8 cold-tier fields of the
+        0.1-degree grid, 207.5 MB) is one frame; a 137-level column is
+        parts, each within the bound."""
+        from repro.core import wire_size
+
+        key = Key({"class": "od", "stream": "enfo", "expver": "0001", "date": "20240601",
+                   "time": "0000", "type": "pf", "levtype": "ml", "number": "1",
+                   "step": "0", "param": "t", "levelist": "137"})
+        cold, hot = wire_size((1801, 3600), 24), wire_size((1801, 3600), 16)
+        assert 8 * cold == 207_475_456
+        bound = P.part_bound()
+        for op in (P.Op.RETRIEVE_BATCH, P.Op.RETRIEVE_MANY):
+            assert len(P.split_reply(op, [(key, cold)] * 8, bound)) == 1
+            assert len(P.split_reply(op, [(key, cold)] * 137, bound)) == 14
+            assert len(P.split_reply(op, [(key, hot)] * 137, bound)) == 7
+        assert P.part_bound() == P.PART_BYTES < P.DEFAULT_MAX_FRAME
+        assert P.part_bound(1 << 20) == 1 << 20
