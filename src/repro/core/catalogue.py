@@ -37,6 +37,10 @@ class ListEntry:
 
 
 class Catalogue(abc.ABC):
+    #: True when ``retrieve`` touches only this process's memory: no file,
+    #: no socket, no sleep, and no lock that a flush holds across its I/O
+    resident: bool = False
+
     def __init__(self, schema: Schema):
         self.schema = schema
 

@@ -153,6 +153,13 @@ class FDBClient(abc.ABC):
     def retrieve(self, key: Key | Mapping[str, str]) -> DataHandle | None:
         return self.retrieve_batch([key])[0]
 
+    def resident(self, key: Key | Mapping[str, str]) -> bool:
+        """True when retrieving *key* and reading its handle touch only this
+        process's memory: no file, no socket, no sleep, and no lock that a
+        flush holds across its I/O.  The wire server serves such a one-field
+        read on its event loop.  False unless a facade knows better."""
+        return False
+
     def _read_handle(self, key: Key | Mapping[str, str], h: DataHandle) -> bytes | None:
         """Drain one handle; on a wipe/migration race (the bytes vanished
         after the catalogue resolved — :class:`FieldGoneError`) re-resolve

@@ -79,6 +79,12 @@ class DaosEngine:
     def n_targets(self) -> int:
         return self.n_engines * self.targets_per_engine
 
+    @property
+    def resident(self) -> bool:
+        """True when every op answers from this process's memory: there is
+        no contention model, or its service times are virtual (not slept)."""
+        return self.contention is None or self.contention.virtual
+
     def _target(self, dkey: str | None) -> int | None:
         return None if dkey is None else hash_dkey_to_target(dkey, self.n_targets)
 

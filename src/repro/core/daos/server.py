@@ -112,6 +112,9 @@ class DaosClient:
     Thread-safe via a per-connection lock.
     """
 
+    #: every op is a round trip over a socket to another process
+    resident = False
+
     def __init__(self, path: str):
         self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         self._sock.connect(path)

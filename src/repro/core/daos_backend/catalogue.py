@@ -59,6 +59,11 @@ class DaosCatalogue(Catalogue):
         """The engine's :class:`DaosStats` (shared telemetry sink)."""
         return self._engine.stats
 
+    @property
+    def resident(self) -> bool:
+        """The engine's: an in-process engine answers from memory."""
+        return self._engine.resident
+
     # ------------------------------------------------------------------ util
     # _mu serialises resolution + cache fill across THIS process's threads
     # (the AsyncFDB writer pool drives archive_batch concurrently); racing

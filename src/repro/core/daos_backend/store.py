@@ -80,6 +80,11 @@ class DaosStore(Store):
         """The engine's :class:`DaosStats` (shared telemetry sink)."""
         return self._engine.stats
 
+    @property
+    def resident(self) -> bool:
+        """The engine's: an in-process engine answers from memory."""
+        return self._engine.resident
+
     # ------------------------------------------------------------------ util
     def _ensure_container(self, name: str) -> None:
         if name in self._containers:

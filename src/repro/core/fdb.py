@@ -129,6 +129,9 @@ class FDB(FDBClient):
             return None  # not an error: FDB may be a cache in a larger system
         return self.store.retrieve(location)
 
+    def resident(self, key: Key | Mapping[str, str]) -> bool:
+        return self.catalogue.resident and self.store.resident
+
     def retrieve_batch(self, keys: Sequence[Key | Mapping[str, str]]) -> list[DataHandle | None]:
         """Vectored ``retrieve``: one Catalogue batch lookup, one Store batch
         open.  Absent fields come back as None."""

@@ -36,6 +36,15 @@ Concurrency model:
   contention lands on the backend's own locks, exactly where the paper
   puts it.  It is the connection's only writer: replies go out through
   ``transport.write`` and wait for the transport's write buffer to drain;
+- a read that names one field (a ``RETRIEVE_BATCH`` of one key, or a
+  ``RETRIEVE_MANY`` with one value for every keyword) runs on the event loop
+  itself, lookup, read and reply, when the served tree answers that key from
+  memory (:meth:`~repro.core.client.FDBClient.resident`: an in-process DAOS
+  tier does; a POSIX tier, a remote tier, a sleeping contention model or a
+  facade that does not say it does, never).  Such a read pays no hand-off
+  to a pool thread and back.  The loop never reads a file, never waits on a
+  lock a flush holds across its I/O and never streams a reply: every other
+  op, and a one-field reply that would stream, goes to the thread pool;
 - a read's reply past the part bound goes, to a peer that negotiated
   :data:`~repro.core.remote.protocol.STREAM_EXT_VERSION`, as ``PART``
   frames of whole items closed by ``END``: the executor thread reads one
@@ -50,6 +59,9 @@ Concurrency model:
 Per-connection wire telemetry (bytes in/out, handling time, coalesced frame
 counts, per-connection op shards) accumulates in ``wire_stats`` — an
 :class:`~repro.metrics.iostats.IOStats` like every other sink in the repo.
+``counters["wire_inline_reads"]`` counts the reads served on the loop; their
+traced ``server.*`` span carries ``inline``, and its ``queued_s`` runs to the
+loop starting the op.
 ``wire_frame_read`` counts the frames read, their body bytes and the seconds
 from each header to its complete body, and ``counters["wire_frame_read_calls"]``
 the ``recv_into`` calls that filled those bodies; a traced op's ``server.*``
@@ -104,6 +116,10 @@ _SERVER_SPANS = {
 
 #: span names of the parts of a streamed reply
 _PART_SPANS = {op: _SERVER_SPANS[op] + ".part" for op in (Op.RETRIEVE_BATCH, Op.RETRIEVE_MANY)}
+
+
+class _Streams(Exception):
+    """A read tried on the event loop whose reply would stream."""
 
 
 class _Frame(NamedTuple):
@@ -564,13 +580,22 @@ class FDBServer:
         return len(items)
 
     async def _run_op(self, frame: _Frame, conn: _Connection) -> None:
+        """Run one op, on the loop where :meth:`_resident_field` says so and
+        otherwise on the thread pool, and send its reply."""
         loop = asyncio.get_running_loop()
         req_id, opcode, _ = P.split_frame(frame.body)
         t0 = time.perf_counter()
+        inline = self._resident_field(frame)
         try:
-            resp_op, payload = await loop.run_in_executor(
-                self._executor, self._serve_op, frame, conn
-            )
+            if inline:
+                try:
+                    resp_op, payload = self._serve_op(frame, conn, inline=True)
+                except _Streams:
+                    inline = False
+            if not inline:
+                resp_op, payload = await loop.run_in_executor(
+                    self._executor, self._serve_op, frame, conn
+                )
         except asyncio.CancelledError:
             raise
         except Exception as e:  # noqa: BLE001 — forwarded to the client
@@ -578,24 +603,55 @@ class FDBServer:
         dt = time.perf_counter() - t0
         base = P.mask_op(opcode)[0]
         nbytes = P.pieces_len(payload) if isinstance(payload, list) else len(payload)
-        self.wire_stats.record(
+        stats = self.wire_stats
+        stats.record(
             f"wire_{Op.NAMES.get(base, hex(base))}",
             seconds=dt, nbytes_w=nbytes, shard=conn.name,
         )
+        if inline:
+            with stats.lock:
+                stats.counters["wire_inline_reads"] += 1
         await conn.send(req_id, resp_op, payload)
 
+    def _resident_field(self, frame: _Frame) -> bool:
+        """Whether *frame* is a read of one field that the served tree
+        answers from memory (module docstring).  A frame that does not
+        decode goes to the thread pool, which answers its error."""
+        _, raw_op, cur = P.split_frame(frame.body)
+        opcode, traced = P.mask_op(raw_op)
+        if opcode not in (Op.RETRIEVE_BATCH, Op.RETRIEVE_MANY):
+            return False
+        try:
+            if traced:
+                P.decode_trace_ctx(cur)
+            if opcode == Op.RETRIEVE_BATCH:
+                keys = P.decode_keys(cur)
+                return len(keys) == 1 and self.fdb.resident(keys[0])
+            req = P.decode_request(cur)
+            if not req.is_exact(self.fdb.schema) or not all(
+                span.is_exact and len(span.values()) == 1 for span in req.values()
+            ):
+                return False
+            return self.fdb.resident({kw: span.values()[0] for kw, span in req.items()})
+        except Exception:  # noqa: BLE001 — the thread pool answers the error
+            return False
+
     # --------------------------------------------------------- op execution
-    def _serve_op(self, frame: _Frame, conn: _Connection) -> tuple[int, bytes | list]:
+    def _serve_op(self, frame: _Frame, conn: _Connection,
+                  inline: bool = False) -> tuple[int, bytes | list]:
         """Decode one request frame, run it against the FDB, and return the
         reply's last frame as ``(opcode, payload)``: ``OK``, or ``END`` after
         the parts of a streamed reply, which this thread has sent; a read's
         one-frame reply is a list of buffers (:func:`P.reply_pieces`).  Runs on
-        the executor thread pool.  A TRACE_FLAG'd frame carries a
+        the executor thread pool, or ``inline`` on the event loop (a read of
+        one field, which raises :class:`_Streams` before it reads if its
+        reply would stream).  A TRACE_FLAG'd frame carries a
         trace-context prefix: the op executes under a server span parented
         to the client's wire span, so the client can stitch the server-side
         time into ONE trace via the Op.TRACE round.  The span's ``queued_s``
         runs from the frame's body being read to this thread starting the
-        op; ``read_s`` and ``read_calls`` are that read."""
+        op; ``read_s`` and ``read_calls`` are that read; ``inline`` is set
+        on an op the loop ran."""
         t_run = time.perf_counter()
         req_id, raw_op, cur = P.split_frame(frame.body)
         opcode, traced = P.mask_op(raw_op)
@@ -616,31 +672,41 @@ class FDBServer:
                 sp.set("queued_s", t_run - frame.t_read)
                 sp.set("read_s", frame.read_s)
                 sp.set("read_calls", frame.read_calls)
+                if inline:
+                    sp.set("inline", True)
             if opcode == Op.RETRIEVE_BATCH:
                 keys = P.decode_keys(cur)
                 return self._reply_fields(
-                    opcode, list(zip(keys, self.fdb.retrieve_batch(keys))), conn, req_id, sp
+                    opcode, list(zip(keys, self.fdb.retrieve_batch(keys))), conn, req_id, sp,
+                    inline,
                 )
             if opcode == Op.RETRIEVE_MANY:
                 fs = self.fdb.retrieve_many(P.decode_request(cur))
                 return self._reply_fields(
-                    opcode, list(zip(fs.keys, fs.handles())), conn, req_id, sp
+                    opcode, list(zip(fs.keys, fs.handles())), conn, req_id, sp, inline
                 )
             return Op.OK, self._dispatch_op(opcode, cur)
 
     def _reply_fields(self, opcode: int, handles: list, conn: _Connection, req_id: int,
-                      sp) -> tuple[int, bytes | list]:
+                      sp, inline: bool = False) -> tuple[int, bytes | list]:
         """A read's reply to ``(key, handle)`` items: one frame, as the
         payload's pieces; or, past the part bound to a peer that streams,
         ``PART`` frames sent from this thread, each part read from the store
         just before it is written, and the next read only once the
         transport has drained (module docstring); then the ``END`` payload.
         The payloads go to the transport as they were read, never joined
-        into a frame."""
+        into a frame.  On the loop (``inline``) a reply that would stream
+        raises :class:`_Streams` instead: the loop cannot wait on its own
+        drain."""
         sizes = [(k, None if h is None else h.size) for k, h in handles]
         ranges = P.split_reply(opcode, sizes, P.part_bound(self._max_frame))
         if len(ranges) == 1 or conn.ext < P.STREAM_EXT_VERSION:
             return Op.OK, P.reply_pieces(opcode, _read_items(handles))
+        if inline:
+            for _, h in handles:
+                if h is not None:
+                    h.close()
+            raise _Streams
         tr, stats = self.tracer, self.wire_stats
         most = 0
         for r in ranges:

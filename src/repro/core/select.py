@@ -332,6 +332,16 @@ class SelectFDB(FDBClient):
         client = self.route(key)
         return None if client is None else self._retrieve_routed(key, client)
 
+    def resident(self, key: Key | Mapping[str, str]) -> bool:
+        """The owning tier's answer; a miss may re-route to another tier
+        once a migration has pinned keys, so then every tier's."""
+        client = self.route(key)
+        if client is None:
+            return True
+        if self._overlay:
+            return all(tier.resident(key) for tier in self.tiers)
+        return client.resident(key)
+
     def retrieve_batch(self, keys: Sequence[Key | Mapping[str, str]]) -> list[DataHandle | None]:
         tr = self._trace
         with tr.span("select.retrieve_batch") as sp:

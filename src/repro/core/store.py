@@ -64,6 +64,11 @@ ArchiveItem = Tuple[bytes, Key, Key]
 class Store(abc.ABC):
     scheme: str
 
+    #: True when ``retrieve`` and the reads of its handles touch only this
+    #: process's memory: no file, no socket, no sleep, and no lock that a
+    #: flush holds across its I/O
+    resident: bool = False
+
     @abc.abstractmethod
     def archive(self, data: bytes, dataset_key: Key, collocation_key: Key) -> FieldLocation:
         ...

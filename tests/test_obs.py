@@ -547,6 +547,22 @@ class TestWireSpans:
         by_id = {s.span_id: s for s in spans}
         assert by_id[served["server.retrieve_many"].parent_id].name == "wire.retrieve_many"
 
+    def test_a_read_on_the_loop_keeps_its_span(self):
+        """The round's one-field retrieve runs on the server's event loop:
+        its span carries ``inline`` beside ``queued_s``, the request still
+        leaves 5 server spans, and the stitched trace holds."""
+        spans = traced_served_round()
+        check_trace_structure(spans)
+        served = [s for s in spans if s.name.startswith("server.")]
+        inline = [s for s in served if (s.attrs or {}).get("inline")]
+        assert [s.name for s in inline] == ["server.retrieve_many"]
+        assert inline[0].attrs["queued_s"] >= 0.0
+        by_id = {s.span_id: s for s in spans}
+        assert by_id[inline[0].parent_id].name == "wire.retrieve_many"
+        server_spans = [s for s in spans if s.proc == "server"
+                        and s.trace_id == inline[0].trace_id]
+        assert len(server_spans) == 5
+
     def test_traced_ops_carry_their_frame_read(self):
         spans = traced_served_round()
         served = [s for s in spans if s.name.startswith("server.")]

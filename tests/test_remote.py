@@ -1046,3 +1046,185 @@ class TestStreamNegotiation:
             assert len(P.split_reply(op, [(key, hot)] * 137, bound)) == 7
         assert P.part_bound() == P.PART_BYTES < P.DEFAULT_MAX_FRAME
         assert P.part_bound(1 << 20) == 1 << 20
+
+
+# ---------------------------------------------------------------------------
+# One-field reads on the server's event loop
+# ---------------------------------------------------------------------------
+
+def _inline_reads(server) -> int:
+    return server.wire_stats.snapshot()["counters"].get("wire_inline_reads", 0)
+
+
+def _read_one(fdb, op: str, key) -> bytes:
+    if op == "retrieve_batch":
+        return fdb.retrieve_batch([key])[0].read()
+    return fdb.retrieve_many(dict(key)).read_all()[key]
+
+
+def _bad_read(op: str, key) -> tuple[int, bytes]:
+    """A one-field read naming a keyword the schema does not have."""
+    if op == "retrieve_batch":
+        return P.Op.RETRIEVE_BATCH, P.encode_keys([Key({**key, "bogus": "1"})])
+    return P.Op.RETRIEVE_MANY, P.encode_request(Request({**key, "bogus": "1"}))
+
+
+def _tree(kind: str, tmp_path):
+    from repro.core.daos import DaosEngine
+    from repro.metrics.contention import DaosContention
+
+    daos = {"backend": "daos", "schema": "nwp-daos"}
+    posix = {"backend": "posix", "schema": "nwp-posix", "root": str(tmp_path / "cold")}
+    return build_fdb({
+        "daos": daos,
+        "posix": posix,
+        "daos-slept": {**daos, "engine": DaosEngine(contention=DaosContention(virtual=False))},
+        "daos-virtual": {**daos, "engine": DaosEngine(contention=DaosContention())},
+        "select": {"type": "select", "rules": [{"match": "number=0", "fdb": daos}], "default": posix},
+        "remote": {"type": "remote", "inner": daos},
+    }[kind])
+
+
+@pytest.mark.parametrize("kind,hot,cold", [
+    ("daos", True, True), ("posix", False, False), ("daos-slept", False, False),
+    ("daos-virtual", True, True), ("select", True, False), ("remote", False, False),
+])
+def test_a_tree_says_which_keys_it_answers_from_memory(kind, hot, cold, tmp_path):
+    """Only what reads this process's memory alone is resident: an
+    in-process DAOS engine whose contention model sleeps nothing, and a
+    select tree's keys that route to one; files, sockets and slept service
+    times never are."""
+    with _tree(kind, tmp_path) as fdb:
+        assert (fdb.resident(ident(num="0")), fdb.resident(ident(num="1"))) == (hot, cold)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("op", ["retrieve_batch", "retrieve_many"])
+@pytest.mark.parametrize("backend,nbits", STREAM_TIERS)
+def test_one_field_reads_run_on_the_loop_where_the_tree_answers_from_memory(
+    backend, nbits, op, traced, tmp_path, servers
+):
+    """A read of one field runs on the server's event loop where the served
+    tree answers from memory (DAOS) and on the thread pool where it reads
+    files (POSIX); other reads, streamed replies and archives always go to
+    the pool.  The answers are the tree's either way, and an op that fails
+    on the loop answers its error on a connection that serves on."""
+    from repro.obs import Tracer
+
+    server = start_server(servers, backend, tmp_path, max_frame=SMALL_FRAME)
+    on_loop = int(backend == "daos")
+    fdb = connect(server, max_frame=SMALL_FRAME, pool_size=1)
+    if traced:
+        fdb.set_tracer(Tracer())
+    with fdb:
+        ref = _column(fdb, nbits)
+        keys = list(ref)
+        assert _inline_reads(server) == 0  # archives and flushes never
+        for k in keys[3:5]:
+            assert _read_one(fdb, op, k) == ref[k] == server.fdb.read(k)
+        assert _inline_reads(server) == 2 * on_loop
+        # two fields, a streamed reply and an archive go to the pool
+        if op == "retrieve_batch":
+            assert [h.read() for h in fdb.retrieve_batch(keys[:2])] == [ref[k] for k in keys[:2]]
+            assert [h.read() for h in fdb.retrieve_batch(keys)] == list(ref.values())
+        else:
+            steps = {**dict(keys[0]), "step": [k["step"] for k in keys]}
+            assert fdb.retrieve_many({**steps, "step": steps["step"][:2]}).read_all() == {
+                k: ref[k] for k in keys[:2]}
+            assert fdb.retrieve_many(steps).read_all() == ref
+        assert server.wire_stats.snapshot()["ops"]["wire_part"] >= 8
+        fdb.archive_batch([(ident(step="99"), b"late")])
+        fdb.flush()
+        assert _inline_reads(server) == 2 * on_loop
+        # a failing one-field op answers ERR, and the connection serves on
+        opcode, payload = _bad_read(op, keys[0])
+        with pytest.raises(RemoteError, match="bogus"):
+            fdb._call(opcode, payload, op)
+        assert _read_one(fdb, op, ident(step="99")) == b"late"
+        assert _inline_reads(server) == 4 * on_loop
+        assert server.wire_stats.snapshot()["ops"]["wire_hello"] == 1
+        if traced:
+            reads = [s for s in server.tracer.spans()
+                     if s.name in ("server.retrieve_batch", "server.retrieve_many")]
+            assert sum(bool((s.attrs or {}).get("inline")) for s in reads) == 4 * on_loop
+            assert all(s.attrs["queued_s"] >= 0.0 for s in reads)
+
+
+def test_a_one_field_reply_that_would_stream_goes_to_the_pool(tmp_path, servers, monkeypatch):
+    """Where the lookup shows that a one-field reply would stream, the loop
+    hands the read to the thread pool, which answers it."""
+    server = start_server(servers, "daos", tmp_path)
+    split_reply, calls = P.split_reply, []
+
+    def streams_once(opcode, items, bound):
+        calls.append(len(items))
+        ranges = split_reply(opcode, items, bound)
+        return ranges * 2 if len(calls) == 1 else ranges
+
+    with connect(server) as fdb:
+        fdb.archive_batch([(ident(), b"one field")])
+        fdb.flush()
+        monkeypatch.setattr(P, "split_reply", streams_once)
+        assert fdb.read(ident()) == b"one field"
+    assert calls == [1, 1]
+    assert _inline_reads(server) == 0
+
+
+def test_a_held_flush_never_holds_the_loop(tmp_path, servers, monkeypatch):
+    """One server fronts a select tree of a DAOS tier and a POSIX tier.  While
+    the POSIX tier's flush is held in a slow fsync, and a one-field POSIX
+    read waits on the file behind it, a one-field DAOS read on another
+    connection answers at once, on the loop: file I/O never runs there.  The
+    POSIX reads answer once the flush ends."""
+    from repro.core.posix.store import _PosixFileHandle
+
+    server = FDBServer({
+        "type": "select",
+        "rules": [{"match": "number=0", "fdb": {"backend": "daos", "schema": "nwp-daos"}}],
+        "default": {"backend": "posix", "schema": "nwp-posix", "root": str(tmp_path / "cold")},
+    })
+    server.start()
+    servers.append(server)
+    hot, cold, late = ident(num="0"), ident(num="1"), ident(num="1", step="1")
+    writer, posix_reader, daos_reader = (connect(server) for _ in range(3))
+    writer.archive_batch([(hot, b"hot"), (cold, b"cold")])
+    writer.flush()
+
+    release, in_fsync, in_read = threading.Event(), threading.Event(), threading.Event()
+    fsync, read_range = os.fsync, _PosixFileHandle.read_range
+
+    def slow_fsync(fd):
+        in_fsync.set()
+        assert release.wait(30)
+        fsync(fd)
+
+    def read_behind_the_flush(self, offset, length):
+        in_read.set()
+        assert release.wait(30)
+        return read_range(self, offset, length)
+
+    monkeypatch.setattr(os, "fsync", slow_fsync)
+    monkeypatch.setattr(_PosixFileHandle, "read_range", read_behind_the_flush)
+    answers: dict = {}
+    writer.archive_batch([(late, b"late")])
+    flushing = threading.Thread(target=writer.flush)
+    reading = threading.Thread(target=lambda: answers.setdefault("cold", posix_reader.read(cold)))
+    try:
+        flushing.start()
+        assert in_fsync.wait(10)
+        reading.start()
+        assert in_read.wait(10)
+        t0 = time.perf_counter()
+        assert daos_reader.read(hot) == b"hot"
+        assert time.perf_counter() - t0 < 5
+        assert flushing.is_alive() and reading.is_alive()
+        assert _inline_reads(server) == 1
+    finally:
+        release.set()
+        flushing.join(30)
+        reading.join(30)
+    assert answers == {"cold": b"cold"}
+    assert posix_reader.read(late) == b"late"
+    assert _inline_reads(server) == 1
+    for c in (writer, posix_reader, daos_reader):
+        c.close()
