@@ -81,21 +81,18 @@ correctness audit (zero failed reads, zero duplicate listings):
 
     PYTHONPATH=src python benchmarks/fdb_hammer.py --churn --procs 8
 
-Remote mode (``--remote``): the MEASURED counterpart of ``--scaling`` —
-serve each backend behind an in-process asyncio
-:class:`~repro.core.remote.FDBServer` and hammer it with REAL client
-processes (``multiprocessing`` spawn, one :class:`RemoteFDB` per process,
-one wire frame per output-step batch).  The measured cells land in
-``BENCH_contention.json`` as ``"<backend>+remote"`` entries (tagged
-``"measured": true``) next to the simulated sweep, so the real knee can be
-read against the virtual-clock one:
-
-    PYTHONPATH=src python benchmarks/fdb_hammer.py --remote --procs 4
+Every figure here comes from this host's CPU, on the wall clock or on the
+contention model's virtual clock: it reproduces the shape of the paper's
+curves, not the speed of a deployment.  Measured numbers come from the
+chip cells under ``bench/`` and are kept in ``PERF_LEDGER.jsonl``.
 """
 
 from __future__ import annotations
 
+import heapq
 import json
+import os
+import tempfile
 import threading
 import time
 from dataclasses import asdict, dataclass, replace
@@ -129,10 +126,8 @@ __all__ = [
     "make_churn_tree",
     "run_hammer_contended",
     "run_hammer_churn",
-    "run_hammer_remote",
     "scaling_sweep",
     "churn_sweep",
-    "remote_sweep",
     "TIERED_CONFIG",
     "TIERED_CODEC_CONFIG",
     "load_config",
@@ -283,13 +278,94 @@ def _step_request(spec: HammerSpec, member: int, step: int) -> dict:
     return base
 
 
+def _bad_reads(datas, n: int, field_size: int) -> int:
+    """How many of *n* expected fields came back missing or short."""
+    return n - len(datas) + sum(1 for d in datas if d is None or len(d) != field_size)
+
+
+def _member_steps(handle, spec: HammerSpec, member: int, mode: str,
+                  payload: bytes, counters: dict):
+    """One ensemble member's output steps as a generator of backend quanta:
+    archive each step and flush it, or read it back ``read_mult`` times.
+    It yields once per field on the per-key paths, once per batch on the
+    batched and codec paths, once per flush and once per read repetition.
+    The wall-clock runner takes it straight through on a thread; the
+    virtual-clock scheduler interleaves members between quanta.  A read
+    that comes back missing or short is COUNTED in
+    ``counters["failed_reads"]``, never asserted, so a failure mid-migration
+    reaches the churn report; both runners raise on a non-zero count."""
+    n = spec.n_params * spec.n_levels
+    for step in range(spec.n_steps):
+        keys = _step_keys(spec, member, step)
+        if mode == "archive":
+            if spec.codec_nbits is not None:
+                # codec path: the whole step batch bit-packs in ONE grib_pack
+                # launch, then lands via archive_batch (nbits stays None —
+                # the facade's tier width rules)
+                handle.archive_fields(keys, _step_fields(spec, member, step))
+                yield
+            elif spec.io == "batched":
+                handle.archive_batch([(k, payload) for k in keys])
+                yield
+            else:  # sync round-trips, or async enqueues to the pool
+                for k in keys:
+                    handle.archive(k, payload)
+                    yield
+            handle.flush()  # once per output step, as the I/O servers do
+            yield
+        else:
+            # read-mostly dissemination: every field is served read_mult
+            # times, each repetition its own quantum (the first round fills
+            # a cache tier when one rides above the backend)
+            for _rep in range(max(1, spec.read_mult)):
+                if spec.codec_nbits is not None:
+                    arrs = handle.retrieve_fields(_step_request(spec, member, step)).arrays()
+                    if arrs.shape != (n, *spec.field_shape):
+                        counters["failed_reads"] += n
+                    yield
+                elif spec.io == "batched":
+                    counters["failed_reads"] += _bad_reads(
+                        handle.read_batch(keys), n, spec.field_size)
+                    yield
+                elif spec.io == "async":  # MARS-style request, parallel batched reads
+                    datas = handle.retrieve_many(_step_request(spec, member, step)).read_all()
+                    counters["failed_reads"] += _bad_reads(
+                        list(datas.values()), n, spec.field_size)
+                    yield
+                else:
+                    for k in keys:
+                        counters["failed_reads"] += _bad_reads(
+                            [handle.read(k)], 1, spec.field_size)
+                        yield
+
+
+def _check_run(spec: HammerSpec, mode: str, io_modes=IO_MODES) -> None:
+    if mode not in ("archive", "retrieve"):
+        raise ValueError(f"unknown mode {mode!r}; pick 'archive' or 'retrieve'")
+    if spec.io not in io_modes:
+        raise ValueError(f"io mode {spec.io!r} cannot run here; pick one of {io_modes}")
+
+
+def _served_mult(spec: HammerSpec, mode: str) -> int:
+    """Bandwidths count bytes SERVED: the retrieve phase moves read_mult×
+    the archived volume (dissemination fan-out)."""
+    return max(1, spec.read_mult) if mode == "retrieve" else 1
+
+
+def _raise_on_failed(failed_reads: int) -> None:
+    if failed_reads:
+        raise RuntimeError(f"{failed_reads} reads came back missing or short")
+
+
 def run_hammer(fdb, spec: HammerSpec, mode: str) -> dict:
-    """mode: 'archive' | 'retrieve' | 'list'.  Returns timings + bandwidth."""
-    if spec.io not in IO_MODES:
-        raise ValueError(f"unknown io mode {spec.io!r}; pick one of {IO_MODES}")
+    """mode: 'archive' | 'retrieve'.  Each member's steps run to completion
+    on a thread of their own, on the wall clock.  Returns timings +
+    bandwidth (global timing, paper §4.3)."""
+    _check_run(spec, mode)
     payload = np.random.default_rng(0).bytes(spec.field_size)
     starts = [0.0] * spec.n_procs
     ends = [0.0] * spec.n_procs
+    counters = [{"failed_reads": 0} for _ in range(spec.n_procs)]
     errors: list[Exception] = []
 
     def proc(member: int) -> None:
@@ -303,53 +379,8 @@ def run_hammer(fdb, spec: HammerSpec, mode: str) -> dict:
             handle = AsyncFDB(fdb, writers=2, batch_size=16)
         try:
             t0 = time.perf_counter()
-            if mode == "archive":
-                for step in range(spec.n_steps):
-                    if spec.codec_nbits is not None:
-                        # codec path: the whole step batch bit-packs in ONE
-                        # grib_pack launch, then lands via archive_batch
-                        # (nbits stays None — the facade's tier width rules)
-                        handle.archive_fields(
-                            _step_keys(spec, member, step), _step_fields(spec, member, step)
-                        )
-                    elif spec.io == "batched":
-                        handle.archive_batch([(k, payload) for k in _step_keys(spec, member, step)])
-                    else:  # sync round-trips, or async enqueues to the pool
-                        for k in _step_keys(spec, member, step):
-                            handle.archive(k, payload)
-                    handle.flush()  # once per output step, as the I/O servers do
-            elif mode == "retrieve":
-                # read-mostly dissemination: every field is served read_mult
-                # times (the first round fills a cache tier when one rides
-                # above the backend; the rest are its hits)
-                reps = [
-                    (rep, step)
-                    for rep in range(max(1, spec.read_mult))
-                    for step in range(spec.n_steps)
-                ]
-                for _rep, step in reps:
-                    if spec.codec_nbits is not None:
-                        arrs = handle.retrieve_fields(_step_request(spec, member, step)).arrays()
-                        assert arrs.shape == (
-                            spec.n_params * spec.n_levels, *spec.field_shape,
-                        )
-                    elif spec.io == "sync":
-                        for k in _step_keys(spec, member, step):
-                            data = handle.read(k)
-                            assert data is not None and len(data) == spec.field_size
-                    elif spec.io == "batched":
-                        datas = handle.read_batch(_step_keys(spec, member, step))
-                        assert all(d is not None and len(d) == spec.field_size for d in datas)
-                    else:  # async: MARS-style request, parallel batched reads
-                        datas = handle.retrieve_many(_step_request(spec, member, step)).read_all()
-                        assert len(datas) == spec.n_params * spec.n_levels
-                        assert all(d is not None and len(d) == spec.field_size for d in datas.values())
-            elif mode == "list":
-                # post-processing pattern: list everything for one step
-                n = sum(1 for _ in handle.list({"step": "0"}))
-                assert n >= spec.n_params * spec.n_levels
-            else:
-                raise ValueError(mode)
+            for _ in _member_steps(handle, spec, member, mode, payload, counters[member]):
+                pass
             starts[member], ends[member] = t0, time.perf_counter()
         except Exception as e:  # noqa: BLE001
             errors.append(e)
@@ -366,11 +397,10 @@ def run_hammer(fdb, spec: HammerSpec, mode: str) -> dict:
     wall = time.perf_counter() - wall0
     if errors:
         raise errors[0]
+    _raise_on_failed(sum(c["failed_reads"] for c in counters))
     span = max(ends) - min(starts)
-    # bandwidths count bytes SERVED: the retrieve phase moves read_mult×
-    # the archived volume (dissemination fan-out)
-    mult = max(1, spec.read_mult) if mode == "retrieve" else 1
-    nbytes = spec.total_bytes * mult if mode != "list" else 0
+    mult = _served_mult(spec, mode)
+    nbytes = spec.total_bytes * mult
     res = {
         "mode": mode,
         "io": spec.io,
@@ -378,11 +408,11 @@ def run_hammer(fdb, spec: HammerSpec, mode: str) -> dict:
         "wall_s": wall,
         # application (pre-codec) bytes over global time — the bandwidth
         # that matters operationally (GRIB traffic is always packed)
-        "bandwidth_GiBps": (nbytes / span / GiB) if nbytes else 0.0,
+        "bandwidth_GiBps": nbytes / span / GiB,
         "fields": spec.fields_per_proc * spec.n_procs * mult,
         "us_per_field": 1e6 * span / max(1, spec.fields_per_proc * spec.n_procs * mult),
     }
-    if spec.codec_nbits is not None and nbytes:
+    if spec.codec_nbits is not None:
         wire = spec.total_wire_bytes * mult
         res["effective_GiBps"] = res["bandwidth_GiBps"]
         res["wire_GiBps"] = wire / span / GiB
@@ -397,8 +427,6 @@ def sweep(spec: HammerSpec, backends=("daos", "posix"), lanes_sweep=(1, 2),
     ``cache_bytes`` each cell runs through a dissemination cache tier and
     reports hit rate + backend bytes saved (pair with ``spec.read_mult`` for
     the read-mostly A/B against a cacheless run)."""
-    import tempfile
-
     rows = []
     for backend in backends:
         for lanes in lanes_sweep:
@@ -522,8 +550,6 @@ def _fill_posix_roots(cfg, scratch: str, counter: list | None = None,
     if isinstance(cfg, dict):
         is_local = cfg.get("type", "local" if "backend" in cfg else None) == "local"
         if is_local and cfg.get("backend") == "posix" and "root" not in cfg:
-            import os
-
             root = os.path.join(scratch, f"tier{counter[0]}")
             cfg["root"] = os.path.join(root, "lane{lane}") if in_template else root
             counter[0] += 1
@@ -541,7 +567,6 @@ def run_config(config: dict, spec: HammerSpec, io_modes=IO_MODES,
     scratch roots per cell, archive then retrieve then a listing, with the
     per-tier/per-lane telemetry breakdown when the tree exposes one."""
     import copy
-    import tempfile
 
     rows = []
     for io in io_modes:
@@ -587,82 +612,49 @@ def run_config(config: dict, spec: HammerSpec, io_modes=IO_MODES,
 # Contended client-scaling sweep (paper Figs 3/4)
 # ---------------------------------------------------------------------------
 
-def _proc_quanta(handle, spec: HammerSpec, member: int, mode: str, payload: bytes):
-    """One hammer process as a generator of per-field backend quanta — the
-    deterministic scheduler interleaves processes between quanta."""
-    for step in range(spec.n_steps):
-        keys = _step_keys(spec, member, step)
-        if mode == "archive":
-            if spec.codec_nbits is not None:
-                # one grib_pack launch for the step batch, then one landing
-                handle.archive_fields(keys, _step_fields(spec, member, step))
-                yield
-            elif spec.io == "batched":
-                handle.archive_batch([(k, payload) for k in keys])
-                yield
-            else:
-                for k in keys:
-                    handle.archive(k, payload)
-                    yield
-            handle.flush()  # once per output step, as the I/O servers do
-            yield
-        elif mode == "retrieve":
-            # dissemination fan-out: each repetition is its own quantum, so
-            # the scheduler interleaves the N× read rounds across processes
-            for _rep in range(max(1, spec.read_mult)):
-                if spec.codec_nbits is not None:
-                    arrs = handle.retrieve_fields(_step_request(spec, member, step)).arrays()
-                    assert arrs.shape == (len(keys), *spec.field_shape)
-                    yield
-                elif spec.io == "batched":
-                    datas = handle.read_batch(keys)
-                    assert all(d is not None and len(d) == spec.field_size for d in datas)
-                    yield
-                else:
-                    for k in keys:
-                        data = handle.read(k)
-                        assert data is not None and len(data) == spec.field_size
-                        yield
-        else:
-            raise ValueError(mode)
-
-
-def run_hammer_contended(fdb, spec: HammerSpec, mode: str, model) -> dict:
-    """Drive ``spec.n_procs`` emulated processes through *fdb* under the
-    contention *model* on its virtual clock.
-
-    Deterministic discrete-event schedule: processes run as generators on
-    ONE thread, and the process with the earliest virtual clock always
-    executes its next quantum, so ops hit the model's resource timelines in
-    near-arrival order (the gap-filling timelines absorb the within-quantum
-    reordering) and the numbers are bit-identical on every run.  Bandwidths
-    use global timing (paper §4.3) on the virtual clock.
-    """
-    import heapq
-
+def _virtual_members(handle, spec: HammerSpec, mode: str, model, counters: dict):
+    """``spec.n_procs`` member programs for the virtual clock, each with its
+    own emulated client.  ``io='async'`` is refused: its writer pool runs
+    on threads of its own, outside the one-thread schedule."""
+    _check_run(spec, mode, io_modes=("sync", "batched"))
     payload = np.random.default_rng(0).bytes(spec.field_size)
     clients = [model.new_client(f"proc{m}") for m in range(spec.n_procs)]
-    gens = [_proc_quanta(fdb, spec, m, mode, payload) for m in range(spec.n_procs)]
-    heap: list[tuple[float, int]] = [(0.0, m) for m in range(spec.n_procs)]
+    gens = [_member_steps(handle, spec, m, mode, payload, counters)
+            for m in range(spec.n_procs)]
+    return gens, clients
+
+
+def _schedule(gens: list, clients: list, model) -> None:
+    """Deterministic discrete-event schedule: every participant runs as a
+    generator on ONE thread, and the one with the earliest virtual clock
+    always executes its next quantum, bound to its emulated client, so ops
+    hit the model's resource timelines in near-arrival order (the
+    gap-filling timelines absorb the within-quantum reordering) and the
+    numbers are bit-identical on every run."""
+    heap: list[tuple[float, int]] = [(0.0, i) for i in range(len(gens))]
     heapq.heapify(heap)
     since_prune = 0
     while heap:
-        _, m = heapq.heappop(heap)
-        with model.bind(clients[m]):
+        _, i = heapq.heappop(heap)
+        with model.bind(clients[i]):
             try:
-                next(gens[m])
+                next(gens[i])
             except StopIteration:
                 continue
-        heapq.heappush(heap, (clients[m].t, m))
+        heapq.heappush(heap, (clients[i].t, i))
         since_prune += 1
         if since_prune >= 256:  # bound timeline growth: nothing dispatches
             since_prune = 0     # before the earliest live clock
             model.prune(heap[0][0])
+
+
+def _virtual_rates(spec: HammerSpec, mode: str, clients: list) -> dict:
+    """Global-timing bandwidth (paper §4.3) of *clients* on the virtual clock."""
     span = max(c.t for c in clients)
-    mult = max(1, spec.read_mult) if mode == "retrieve" else 1
+    mult = _served_mult(spec, mode)
     bytes_per_proc = spec.fields_per_proc * spec.field_size * mult
     per_proc = [bytes_per_proc / c.t / GiB for c in clients]
-    res = {
+    return {
         "mode": mode,
         "n_procs": spec.n_procs,
         "span_s": span,
@@ -671,12 +663,23 @@ def run_hammer_contended(fdb, spec: HammerSpec, mode: str, model) -> dict:
         "per_proc_GiBps_mean": sum(per_proc) / len(per_proc),
         "us_per_field": 1e6 * span / max(1, spec.fields_per_proc * spec.n_procs * mult),
     }
+
+
+def run_hammer_contended(fdb, spec: HammerSpec, mode: str, model) -> dict:
+    """Drive ``spec.n_procs`` emulated processes through *fdb* under the
+    contention *model* on its virtual clock (:func:`_schedule`).
+    Bandwidths use global timing (paper §4.3) on the virtual clock."""
+    counters = {"failed_reads": 0}
+    gens, clients = _virtual_members(fdb, spec, mode, model, counters)
+    _schedule(gens, clients, model)
+    _raise_on_failed(counters["failed_reads"])
+    res = _virtual_rates(spec, mode, clients)
     if spec.codec_nbits is not None:
         # the contention model charges the WIRE bytes, but the run moved
         # total_bytes of application data: effective/wire is the codec win
-        wire = spec.total_wire_bytes * mult
         res["effective_GiBps"] = res["agg_GiBps"]
-        res["wire_GiBps"] = wire / span / GiB
+        res["wire_GiBps"] = (spec.total_wire_bytes * _served_mult(spec, mode)
+                             / res["span_s"] / GiB)
         res["codec_ratio"] = spec.total_bytes / spec.total_wire_bytes
     return res
 
@@ -731,6 +734,22 @@ def read_slo_knee(per_proc_curve: list[float], procs_list, floor: float) -> int:
     return best
 
 
+def _merge_bench(out: str | None, cells: dict, **fields) -> dict:
+    """Merge one sweep's *cells* and top-level *fields* into the JSON
+    document at *out* (read if it exists, written back sorted); returns the
+    merged document.  ``out=None`` merges into an empty one."""
+    doc: dict = {}
+    if out and os.path.exists(out):
+        with open(out) as f:
+            doc = json.load(f)
+    doc.setdefault("backends", {}).update(cells)
+    doc.update(fields)
+    if out:
+        with open(out, "w") as f:
+            json.dump(doc, f, indent=2, sort_keys=True)
+    return doc
+
+
 def scaling_sweep(
     spec: HammerSpec,
     backends=("posix", "daos"),
@@ -746,9 +765,8 @@ def scaling_sweep(
     model per cell, archive then retrieve, per-proc and aggregate bandwidth
     plus latency percentiles from the metrics package; the analytical curve
     from :mod:`repro.simulation.cluster` rides along for cross-checking.
-    Cells MERGE into an existing *out* document (matching
-    :func:`remote_sweep`), so codec/cache/remote runs accumulate into one
-    BENCH artifact.
+    Cells MERGE into an existing *out* document (:func:`_merge_bench`), so
+    raw/codec/cache/churn runs accumulate into one BENCH artifact.
 
     ``codec_nbits`` adds a codec cell per backend (labelled
     ``"<backend>+codec<n>"``, raw cells keep their plain labels): the same
@@ -763,21 +781,7 @@ def scaling_sweep(
     A/B against the raw cell).  Every cell additionally reports the
     read-side SLO knee — the widest client count whose per-proc read
     bandwidth holds half the raw single-client rate."""
-    import os
-    import tempfile
-
-    results: dict = {}
-    if out and os.path.exists(out):
-        with open(out) as f:
-            results = json.load(f)
-    results.setdefault("backends", {})
-    results.update(
-        spec=asdict(spec),
-        virtual_clock=virtual,
-        procs_list=list(procs_list),
-        codec_nbits=codec_nbits,
-        cache_bytes=cache_bytes,
-    )
+    done: dict = {}
     cells: list[tuple[str, str, int | None, bool]] = []
     for backend in backends:
         cells.append((backend, backend, None, False))
@@ -820,29 +824,28 @@ def scaling_sweep(
                     fdb.close()
             rows.append({"n_procs": n, "write": w, "read": r})
         per_proc = [row["write"]["per_proc_GiBps_mean"] for row in rows]
-        results["backends"][label] = {
+        done[label] = {
             "sweep": rows,
             "knee_n_procs": find_knee(per_proc, list(procs_list)),
             "analytic": analytic_curve(backend, procs_list, spec),
             "read_mult": spec.read_mult,
         }
         if nbits is not None:
-            results["backends"][label]["codec_nbits"] = nbits
+            done[label]["codec_nbits"] = nbits
         if cached:
-            results["backends"][label]["cache_bytes"] = cache_bytes
+            done[label]["cache_bytes"] = cache_bytes
     # read-side SLO knee for this run's cells: floor = half the raw
     # single-client read rate of each cell's base backend
     for label, backend, _nbits, _cached in cells:
-        raw = results["backends"].get(backend, {}).get("sweep", [])
-        entry = results["backends"][label]
+        raw = done[backend]["sweep"]
+        entry = done[label]
         curve = [row["read"]["per_proc_GiBps_mean"] for row in entry["sweep"]]
-        floor = 0.5 * (raw[0]["read"]["per_proc_GiBps_mean"] if raw else curve[0])
+        floor = 0.5 * raw[0]["read"]["per_proc_GiBps_mean"]
         entry["read_slo_floor_GiBps"] = floor
         entry["read_slo_knee_n_procs"] = read_slo_knee(curve, list(procs_list), floor)
-    if out:
-        with open(out, "w") as f:
-            json.dump(results, f, indent=2, sort_keys=True)
-    return results
+    return _merge_bench(out, done, spec=asdict(spec), virtual_clock=virtual,
+                        procs_list=list(procs_list), codec_nbits=codec_nbits,
+                        cache_bytes=cache_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -862,8 +865,6 @@ def make_churn_tree(backend: str, root: str, model, spec: HammerSpec,
     churn loop advances to the migrator's virtual time (it stays 0 through
     the archive phase, so every field is immediately demotion-due once the
     migrator starts)."""
-    import os
-
     if backend == "daos":
         # two engines = two namespaces (tiers must not share catalogues),
         # ONE model = one set of modelled NVM/fabric resources
@@ -891,21 +892,6 @@ def make_churn_tree(backend: str, root: str, model, spec: HammerSpec,
     return lf, clk
 
 
-def _churn_read_quanta(handle, spec: HammerSpec, member: int, counters: dict):
-    """Foreground read stream for the churn phase: like the contended
-    retrieve path, but read failures are COUNTED (the audit the cell
-    publishes), not asserted — a failed read mid-migration is the bug the
-    benchmark exists to rule out, so it must reach the report."""
-    for step in range(spec.n_steps):
-        keys = _step_keys(spec, member, step)
-        for _rep in range(max(1, spec.read_mult)):
-            datas = handle.read_batch(keys)
-            for d in datas:
-                if d is None or len(d) != spec.field_size:
-                    counters["failed_reads"] += 1
-            yield
-
-
 def _migrator_quanta(lf: LifecycleFDB, clk: list, client, counters: dict):
     """The migration engine as one more discrete-event participant: each
     copy/flip/remove batch is a quantum charged to the migrator's own
@@ -930,43 +916,21 @@ def run_hammer_churn(lf: LifecycleFDB, clk: list, spec: HammerSpec, model,
     every archived field under the contention model; with ``migrate`` the
     lifecycle engine joins the same deterministic schedule as an extra
     participant.  Bandwidths count FOREGROUND clients only — migration is
-    overhead, and its cost shows up as their slowdown."""
-    import heapq
-
-    clients = [model.new_client(f"proc{m}") for m in range(spec.n_procs)]
+    overhead, and its cost shows up as their slowdown.  Failed reads are
+    reported, not raised: they are the audit the cell publishes."""
     counters = {"failed_reads": 0, "fields_migrated": 0, "migration_batches": 0}
-    gens = [_churn_read_quanta(lf, spec, m, counters) for m in range(spec.n_procs)]
+    gens, clients = _virtual_members(lf, spec, "retrieve", model, counters)
+    fg = list(clients)
     if migrate:
         mig = model.new_client("migrator")
         gens.append(_migrator_quanta(lf, clk, mig, counters))
         clients.append(mig)
-    heap: list[tuple[float, int]] = [(0.0, i) for i in range(len(gens))]
-    heapq.heapify(heap)
-    since_prune = 0
-    while heap:
-        _, i = heapq.heappop(heap)
-        with model.bind(clients[i]):
-            try:
-                next(gens[i])
-            except StopIteration:
-                continue
-        heapq.heappush(heap, (clients[i].t, i))
-        since_prune += 1
-        if since_prune >= 256:
-            since_prune = 0
-            model.prune(heap[0][0])
-    fg = clients[: spec.n_procs]
-    span = max(c.t for c in fg)
-    mult = max(1, spec.read_mult)
-    bytes_per_proc = spec.fields_per_proc * spec.field_size * mult
-    per_proc = [bytes_per_proc / c.t / GiB for c in fg]
+    _schedule(gens, clients, model)
+    rates = _virtual_rates(spec, "retrieve", fg)
     return {
-        "mode": "retrieve",
         "migrate": migrate,
-        "n_procs": spec.n_procs,
-        "span_s": span,
-        "agg_GiBps": spec.total_bytes * mult / span / GiB,
-        "per_proc_GiBps_mean": sum(per_proc) / len(per_proc),
+        **{k: rates[k] for k in ("mode", "n_procs", "span_s", "agg_GiBps",
+                                 "per_proc_GiBps_mean")},
         **counters,
     }
 
@@ -988,16 +952,7 @@ def churn_sweep(
     read bandwidth for both runs, their ratio (the interference), fields
     migrated, and the correctness audit: zero failed reads, zero duplicate
     listing entries (exactly one visible catalogue copy per field)."""
-    import os
-    import tempfile
-
-    results: dict = {}
-    if out and os.path.exists(out):
-        with open(out) as f:
-            results = json.load(f)
-    results.setdefault("backends", {})
-    results["churn_procs_list"] = list(procs_list)
-
+    done: dict = {}
     for backend in backends:
         label = f"{backend}+churn"
         rows = []
@@ -1042,146 +997,12 @@ def churn_sweep(
                 "base": base,
                 "churn": churn,
             })
-        results["backends"][label] = {
+        done[label] = {
             "sweep": rows,
             "read_mult": spec.read_mult,
             "migration": True,
         }
-    if out:
-        with open(out, "w") as f:
-            json.dump(results, f, indent=2, sort_keys=True)
-    return results
-
-
-# ---------------------------------------------------------------------------
-# Remote mode (--remote): real client processes against the asyncio server
-# ---------------------------------------------------------------------------
-
-def _remote_proc_worker(addr: str, spec_kw: dict, member: int, mode: str):
-    """One hammer client as a REAL OS process: its own RemoteFDB (own
-    sockets, own GIL), one wire frame per output-step batch.  Module
-    top-level so ``multiprocessing`` spawn can pickle it by reference.
-    It moves raw bytes only and never runs the codec: a device belongs to
-    one process, so N children must not reach for it (``main`` refuses
-    ``--remote`` with ``--codec-nbits``).
-    Returns wall-clock ``(start, end)`` — ``time.time()`` because the global
-    timing span (paper §4.3) is computed ACROSS processes, and only the
-    wall clock is shared between them."""
-    spec = HammerSpec(**spec_kw)
-    payload = np.random.default_rng(0).bytes(spec.field_size)
-    from repro.core.remote import RemoteFDB
-
-    fdb = RemoteFDB(addr, timeout=300.0)
-    try:
-        # deliberately time.time(), NOT time.perf_counter(): perf_counter
-        # epochs are per-process and these timestamps are differenced
-        # across processes in run_hammer_remote
-        t0 = time.time()
-        for step in range(spec.n_steps):
-            keys = _step_keys(spec, member, step)
-            if mode == "archive":
-                fdb.archive_batch([(k, payload) for k in keys])
-                fdb.flush()  # once per output step, as the I/O servers do
-            elif mode == "retrieve":
-                datas = fdb.read_batch(keys)
-                assert all(
-                    d is not None and len(d) == spec.field_size for d in datas
-                )
-            else:
-                raise ValueError(mode)
-        return t0, time.time()
-    finally:
-        fdb.close()
-
-
-def run_hammer_remote(addr: str, spec: HammerSpec, mode: str) -> dict:
-    """Drive ``spec.n_procs`` REAL client processes against the FDB served
-    at *addr*.  Spawn (not fork): the parent holds JAX thread pools and an
-    asyncio loop, neither survives forking.  Bandwidths use global timing
-    across the processes' wall clocks."""
-    import multiprocessing
-
-    ctx = multiprocessing.get_context("spawn")
-    jobs = [(addr, asdict(spec), m, mode) for m in range(spec.n_procs)]
-    with ctx.Pool(processes=spec.n_procs) as pool:
-        times = pool.starmap(_remote_proc_worker, jobs)
-    span = max(t1 for _, t1 in times) - min(t0 for t0, _ in times)
-    span = max(span, 1e-9)
-    bytes_per_proc = spec.fields_per_proc * spec.field_size
-    per_proc = [bytes_per_proc / max(t1 - t0, 1e-9) / GiB for t0, t1 in times]
-    return {
-        "mode": mode,
-        "n_procs": spec.n_procs,
-        "span_s": span,
-        "agg_GiBps": spec.total_bytes / span / GiB,
-        "per_proc_GiBps": per_proc,
-        "per_proc_GiBps_mean": sum(per_proc) / len(per_proc),
-        "us_per_field": 1e6 * span / max(1, spec.fields_per_proc * spec.n_procs),
-        "measured": True,
-    }
-
-
-def remote_sweep(
-    spec: HammerSpec,
-    backends=("posix", "daos"),
-    procs_list=(1, 2, 4),
-    *,
-    out: str | None = "BENCH_contention.json",
-) -> dict:
-    """Measured client-scaling cells: serve each backend behind an asyncio
-    :class:`~repro.core.remote.FDBServer`, hammer it with real client
-    processes, and MERGE the ``"<backend>+remote"`` cells (tagged
-    ``"measured": true``) into *out* next to whatever simulated sweep is
-    already there — the acceptance comparison reads both from one file."""
-    import os
-    import tempfile
-
-    from repro.core.remote import FDBServer
-
-    results: dict = {}
-    if out and os.path.exists(out):
-        with open(out) as f:
-            results = json.load(f)
-    results.setdefault("backends", {})
-    results.setdefault("spec", asdict(spec))
-    results["remote_procs_list"] = list(procs_list)
-
-    for backend in backends:
-        label = f"{backend}+remote"
-        rows = []
-        for n in procs_list:
-            cell = replace(spec, n_procs=n)
-            with tempfile.TemporaryDirectory() as td:
-                cfg = {"backend": backend}
-                if backend == "posix":
-                    cfg["root"] = td
-                server = FDBServer(cfg)
-                host, port = server.start()
-                try:
-                    addr = f"{host}:{port}"
-                    w = run_hammer_remote(addr, cell, "archive")
-                    r = run_hammer_remote(addr, cell, "retrieve")
-                    wire = server.wire_stats.snapshot()
-                finally:
-                    server.stop()
-            rows.append({
-                "n_procs": n, "write": w, "read": r, "measured": True,
-                "wire": {
-                    "bytes_read": wire.get("bytes_read", 0),
-                    "bytes_written": wire.get("bytes_written", 0),
-                    "connections": len(wire.get("shard_ops", {})),
-                },
-            })
-        per_proc = [row["write"]["per_proc_GiBps_mean"] for row in rows]
-        results["backends"][label] = {
-            "sweep": rows,
-            "knee_n_procs": find_knee(per_proc, list(procs_list)),
-            "measured": True,
-        }
-    if out:
-        with open(out, "w") as f:
-            json.dump(results, f, indent=2, sort_keys=True)
-    return results
+    return _merge_bench(out, done, churn_procs_list=list(procs_list))
 
 
 def _pow2_upto(n: int) -> list[int]:
@@ -1208,22 +1029,13 @@ def main() -> None:
                     help="contended client-scaling sweep (1..procs, powers of two) "
                          "through the contention model on a virtual clock")
     ap.add_argument("--churn", action="store_true",
-                    help="churn-interference sweep: per backend/client count, "
-                         "re-read every field with the data-lifecycle engine "
-                         "idle (baseline) and again while it demotes all but "
-                         "the newest step between the tiers of a two-tier "
-                         "select — '<backend>+churn' cells (foreground "
-                         "bandwidth with/without migration, interference "
-                         "ratio, audit counters) merge into the --out JSON")
-    ap.add_argument("--remote", action="store_true",
-                    help="MEASURED client-scaling sweep: serve each backend "
-                         "behind the asyncio FDB server and hammer it with real "
-                         "client processes (multiprocessing spawn, one RemoteFDB "
-                         "per process); '<backend>+remote' cells merge into the "
-                         "--out JSON next to any simulated sweep already there")
+                    help="churn-interference sweep: re-read every field with "
+                         "the data-lifecycle engine idle, then while it demotes "
+                         "all but the newest step between two tiers; "
+                         "'<backend>+churn' cells merge into the --out JSON")
     ap.add_argument("--io", choices=IO_MODES, default="sync")
     ap.add_argument("--out", default="BENCH_contention.json",
-                    help="output JSON for --scaling")
+                    help="output JSON for --scaling and --churn")
     ap.add_argument("--request", default=None, metavar="MARS",
                     help="populate the backends, then retrieve this MARS-style "
                          'request through the shared client surface (e.g. '
@@ -1262,11 +1074,8 @@ def main() -> None:
     ap.add_argument("--cache-bytes", type=int, default=256 << 20, metavar="B",
                     help="cache tier byte budget for --cache (default 256 MiB)")
     args = ap.parse_args()
-    if args.remote and args.codec_nbits is not None:
-        ap.error("--remote runs raw-byte client processes and cannot take "
-                 "--codec-nbits: the codec runs on the device, and one device "
-                 "belongs to one process, not to every client")
-
+    if args.scaling and args.io == "async":
+        ap.error("--scaling schedules every client on one thread; pick --io sync or batched")
     from repro.compile_cache import use_compile_cache
 
     use_compile_cache()
@@ -1305,8 +1114,6 @@ def main() -> None:
         return
 
     if args.request:
-        import tempfile
-
         lanes = args.lanes[0]  # request mode is a single cell, not a sweep
         spec = replace(spec, n_datasets=max(spec.n_datasets, lanes))
         print(f"fdb-hammer request mode: {args.request!r} over "
@@ -1341,26 +1148,6 @@ def main() -> None:
                       f"{row['interference_ratio']:12.3f} {row['fields_migrated']:9d} "
                       f"{row['failed_reads']:7d} {row['duplicate_reads']:5d}")
         print(f"\nmerged churn cells into {args.out}")
-        return
-
-    if args.remote:
-        procs_list = _pow2_upto(args.procs)
-        print(f"fdb-hammer remote sweep (real processes): n_procs in {procs_list}, "
-              f"{spec.fields_per_proc} fields x {spec.field_size} B per proc\n")
-        results = remote_sweep(spec, backends=tuple(args.backends),
-                               procs_list=procs_list, out=args.out)
-        print(f"{'backend':16s} {'procs':>5s} {'write agg':>10s} {'write/proc':>11s} "
-              f"{'read/proc':>10s} {'conns':>6s}")
-        for backend in args.backends:
-            data = results["backends"][f"{backend}+remote"]
-            for row in data["sweep"]:
-                w, r = row["write"], row["read"]
-                print(f"{backend + '+remote':16s} {row['n_procs']:5d} "
-                      f"{w['agg_GiBps']:10.3f} {w['per_proc_GiBps_mean']:11.3f} "
-                      f"{r['per_proc_GiBps_mean']:10.3f} "
-                      f"{row['wire']['connections']:6d}")
-            print(f"{backend + '+remote':16s} knee at n_procs={data['knee_n_procs']}")
-        print(f"\nmerged measured cells into {args.out}")
         return
 
     if args.scaling:

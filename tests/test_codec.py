@@ -617,17 +617,6 @@ class TestHammerCodec:
         assert row["wire_bytes_written"] > 0
         assert row["codec_ratio_w"] > 1.0  # hot 16-bit tier wins, cold 24 rides uint32
 
-    def test_remote_refuses_codec(self, hammer, monkeypatch, capsys):
-        """Remote clients are separate processes that move raw bytes; the
-        codec needs the device, which one process holds."""
-        monkeypatch.setattr(
-            sys, "argv", ["fdb_hammer.py", "--remote", "--codec-nbits", "16"]
-        )
-        with pytest.raises(SystemExit) as exc:
-            hammer.main()
-        assert exc.value.code == 2
-        assert "--codec-nbits" in capsys.readouterr().err
-
 
 # ---------------------------------------------------------------------------
 # streamed replies: the whole set decodes part by part as the parts land

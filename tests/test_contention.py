@@ -6,14 +6,24 @@ stays within 20% of its single-client value (paper §4/§5.1, Figs 3/4;
 companion paper arXiv:2211.09162)."""
 
 import json
+import math
 import os
 import sys
+from dataclasses import replace
 
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 
-from fdb_hammer import HammerSpec, scaling_sweep  # noqa: E402
+from fdb_hammer import (  # noqa: E402
+    IO_MODES,
+    HammerSpec,
+    churn_sweep,
+    make_backend,
+    run_hammer,
+    run_hammer_contended,
+    scaling_sweep,
+)
 
 from repro.metrics import LustreContention, make_contention  # noqa: E402
 from repro.metrics.contention import _Timeline  # noqa: E402
@@ -150,3 +160,100 @@ class TestContentionModelUnits:
             total = sum(cm.write("/seg", 1 << 26) for _ in range(100))
         assert total > 1.0          # >1 virtual second injected
         assert time.perf_counter() - t0 < 0.5  # ...in well under real-time
+
+
+class _CallLog:
+    """Records the name of every method called on the wrapped FDB."""
+
+    def __init__(self, inner):
+        self.inner, self.calls = inner, []
+
+    def __getattr__(self, name):
+        attr = getattr(self.inner, name)
+
+        def call(*args, **kwargs):
+            self.calls.append(name)
+            return attr(*args, **kwargs)
+
+        return call
+
+
+class TestHammerMemberProgram:
+    """One member program drives the wall-clock threads, the virtual-clock
+    scheduler and the churn read phase."""
+
+    @pytest.mark.parametrize("io", ("sync", "batched"))
+    def test_both_clocks_issue_the_same_calls(self, io):
+        spec = HammerSpec(n_procs=1, n_steps=2, n_params=2, n_levels=2,
+                          field_size=2048, io=io, read_mult=2)
+        threaded = _CallLog(make_backend("daos"))
+        model = make_contention("daos")
+        virtual = _CallLog(make_backend("daos", contention=model))
+        for mode in ("archive", "retrieve"):
+            run_hammer(threaded, spec, mode)
+            run_hammer_contended(virtual, spec, mode, model)
+        assert threaded.calls == virtual.calls
+        per_step = ["archive_batch"] if io == "batched" else ["archive"] * 4
+        reads = ["read_batch"] if io == "batched" else ["read"] * 4
+        assert threaded.calls == (per_step + ["flush"]) * 2 + reads * 4
+        threaded.inner.close()
+        virtual.inner.close()
+
+    def test_contended_refuses_async(self):
+        model = make_contention("daos")
+        fdb = make_backend("daos", contention=model)
+        spec = HammerSpec(n_procs=1, n_steps=1, n_params=1, n_levels=1, io="async")
+        with pytest.raises(ValueError, match="async"):
+            run_hammer_contended(fdb, spec, "archive", model)
+        fdb.close()
+
+    def test_scaling_cli_refuses_async(self, monkeypatch, capsys):
+        import fdb_hammer
+
+        monkeypatch.setattr(sys, "argv", ["fdb_hammer.py", "--scaling", "--io", "async"])
+        with pytest.raises(SystemExit) as exc:
+            fdb_hammer.main()
+        assert exc.value.code == 2
+        assert "--io sync or batched" in capsys.readouterr().err
+
+    @pytest.mark.parametrize("io", IO_MODES)
+    def test_run_hammer_raises_on_missing_fields(self, io):
+        # member 1 never archived: its reads come back empty, are counted,
+        # and the run raises once every thread has finished
+        spec = HammerSpec(n_procs=1, n_steps=1, n_params=2, n_levels=2,
+                          field_size=2048, io=io)
+        fdb = make_backend("daos")
+        run_hammer(fdb, spec, "archive")
+        with pytest.raises(RuntimeError, match="4 reads"):
+            run_hammer(fdb, replace(spec, n_procs=2), "retrieve")
+        fdb.close()
+
+    @pytest.mark.parametrize("io", ("sync", "batched"))
+    def test_contended_raises_on_missing_fields(self, io):
+        spec = HammerSpec(n_procs=1, n_steps=1, n_params=2, n_levels=2,
+                          field_size=2048, io=io)
+        model = make_contention("daos")
+        fdb = make_backend("daos", contention=model)
+        run_hammer_contended(fdb, spec, "archive", model)
+        with pytest.raises(RuntimeError, match="4 reads"):
+            run_hammer_contended(fdb, replace(spec, n_procs=2),
+                                 "retrieve", model)
+        fdb.close()
+
+    def test_churn_sweep_audit_is_clean_and_deterministic(self):
+        """The churn cells at CI's shape: migration moves fields on both
+        backends while no read fails and no field is listed twice."""
+        spec = HammerSpec(n_steps=3, n_params=2, n_levels=2)
+        first = churn_sweep(spec, procs_list=(1, 2), out=None)
+        again = churn_sweep(spec, procs_list=(1, 2), out=None)
+        assert json.dumps(first, sort_keys=True) == json.dumps(again, sort_keys=True)
+        assert first["churn_procs_list"] == [1, 2]
+        for backend in ("posix", "daos"):
+            rows = first["backends"][f"{backend}+churn"]["sweep"]
+            assert [r["n_procs"] for r in rows] == [1, 2]
+            for row in rows:
+                assert math.isfinite(row["interference_ratio"]), row
+                assert row["interference_ratio"] > 0, row
+                assert row["failed_reads"] == 0, row
+                assert row["duplicate_reads"] == 0, row
+                assert row["fields_migrated"] > 0, row
